@@ -59,9 +59,11 @@ def _quick() -> bool:
     return os.environ.get("REPRO_BENCH_QUICK", "") == "1"
 
 
-def build_db(n_fact=N_FACT, n_dim=N_DIM) -> VerticaDB:
-    fact, dim = star_schema(n_fact, n_dim)
-    db = VerticaDB(n_nodes=4, k_safety=0, block_rows=4096)
+def build_db(n_fact=N_FACT, n_dim=N_DIM, *, seed: int = 0,
+             cache_budget_bytes: int = 256 << 20) -> VerticaDB:
+    fact, dim = star_schema(n_fact, n_dim, seed)
+    db = VerticaDB(n_nodes=4, k_safety=0, block_rows=4096,
+                   cache_budget_bytes=cache_budget_bytes)
     schema = TableSchema("lineitem", (
         ColumnDef("l_orderkey"), ColumnDef("l_suppkey"),
         ColumnDef("l_shipdate"), ColumnDef("l_qty"),
@@ -150,74 +152,6 @@ def _time(fn, reps=3):
 # the same executor but their dim placement cost is covered by the
 # differential tests; this tracks the scan->exchange->aggregate spine)
 SEG_NAMES = ("Q2", "Q3", "Q4", "Q6")
-
-
-def _run_mesh8():
-    """Subprocess entry (``--mesh8``): re-run the segmented subset on a
-    forced 8-device host mesh and print one JSON line.  Device count is
-    fixed at process start, so the scale-out point needs its own
-    process; the parent treats any failure as 'skipped'."""
-    import json
-    n_fact = QUICK_N_FACT if _quick() else N_FACT
-    n_dim = QUICK_N_DIM if _quick() else N_DIM
-    db = build_db(n_fact, n_dim)
-    queries = {n: qb.to_ir() for n, qb in make_builders(db).items()}
-    single = sum(_time(lambda q=queries[n]: execute(db, q)[0])
-                 for n in SEG_NAMES)
-    mesh = db.attach_mesh()
-    n_shards = int(mesh.shape["data"])
-    seg = 0.0
-    seg_all = True
-    for name in SEG_NAMES:
-        last = {}
-
-        def run_seg(q=queries[name], last=last):
-            out, st = execute(db, q)
-            last["stats"] = st
-            return out
-        seg += _time(run_seg)
-        seg_all &= last["stats"].segmented
-    # per-stage wall clocks (ExecStats.stage_ms): one extra warm pass per
-    # query with stage syncs enabled -- the timed loop above stays
-    # sync-free so stage accounting never distorts the headline number
-    stage_ms = {}
-    db.collect_stage_timing = True
-    for name in SEG_NAMES:
-        _, st = execute(db, queries[name])
-        for k, v in st.stage_ms.items():
-            stage_ms[k] = stage_ms.get(k, 0.0) + v
-    db.collect_stage_timing = False
-    db.detach_mesh()
-    print(json.dumps({
-        "n_shards": n_shards, "n_fact": n_fact,
-        "segmented_s": seg, "single_node_s": single,
-        "speedup_vs_single_node": single / seg,
-        "stage_ms": {k: round(v, 2) for k, v in stage_ms.items()},
-        "all_segmented": bool(seg_all)}))
-
-
-def _mesh8_row(timeout_s: int = 2400):
-    """The 8-device mesh tier of the segmented bench, via subprocess
-    (XLA device count is a process-start flag).  Never breaks the main
-    bench: any failure or REPRO_BENCH_SKIP_MESH8=1 records a skip."""
-    import json
-    import subprocess
-    if os.environ.get("REPRO_BENCH_SKIP_MESH8", "") == "1":
-        return {"skipped": "REPRO_BENCH_SKIP_MESH8=1"}
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8"
-                        ).strip()
-    try:
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--mesh8"],
-            env=env, capture_output=True, text=True, timeout=timeout_s,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-        line = [ln for ln in proc.stdout.strip().splitlines()
-                if ln.startswith("{")][-1]
-        return json.loads(line)
-    except Exception as e:                        # noqa: BLE001
-        return {"skipped": f"{type(e).__name__}: {e}"[:200]}
 
 
 # the predicate subset of the workload used by the compression tier
@@ -414,9 +348,11 @@ def run(report):
               f"{stats.block_cache_hits}h/{stats.block_cache_misses}m  "
               f"pruned {stats.blocks_pruned}/{stats.blocks_total}")
     # --- segmented-vs-single-node: the same warm queries routed through
-    # the multi-device executor (engine/segmented.py); on a 1-device CPU
-    # run this measures pure segmentation overhead, on N devices the
-    # scale-out win.  Recorded into BENCH_cstore.json PR-over-PR. ---
+    # the multi-device executor (engine/segmented.py) over every device
+    # this process holds; on one device this measures pure segmentation
+    # overhead, on N devices the scale-out.  Runs in this process (a child
+    # could not reach a chip the parent holds); a failure fails the run.
+    # Recorded into BENCH_cstore.json PR-over-PR. ---
     seg_names = SEG_NAMES
     mesh = db.attach_mesh()
     n_shards = int(mesh.shape["data"])
@@ -433,28 +369,27 @@ def run(report):
         ts = _time(run_seg)
         seg_all &= last["stats"].segmented
         seg_total += ts
+    # per-stage wall clocks (ExecStats.stage_ms): one extra warm pass per
+    # query with stage syncs enabled -- the timed loop above stays
+    # sync-free so stage accounting never distorts the headline number
+    stage_ms = {}
+    db.collect_stage_timing = True
+    for name in seg_names:
+        _, st = execute(db, QUERIES[name])
+        for k, v in st.stage_ms.items():
+            stage_ms[k] = stage_ms.get(k, 0.0) + v
+    db.collect_stage_timing = False
     db.detach_mesh()
     single_total = sum(rows[n]["vertica_ms"] for n in seg_names) / 1e3
     seg_row = {"n_shards": n_shards, "queries": list(seg_names),
                "segmented_s": seg_total, "single_node_s": single_total,
                "speedup_vs_single_node": single_total / seg_total,
+               "stage_ms": {k: round(v, 2) for k, v in stage_ms.items()},
                "all_segmented": bool(seg_all)}
     print(f"[cstore] segmented ({n_shards} shard(s)): "
           f"{seg_total*1e3:.1f}ms vs single-node "
           f"{single_total*1e3:.1f}ms = "
           f"{single_total/seg_total:.2f}x over {list(seg_names)}")
-    # scale-out point: same subset on a forced 8-device host mesh (its
-    # own process; XLA fixes device count at start).  Records BOTH the
-    # 1-shard overhead ratio above and the mesh-tier ratio PR-over-PR.
-    seg_row["mesh8"] = _mesh8_row()
-    m8 = seg_row["mesh8"]
-    if "skipped" in m8:
-        print(f"[cstore] segmented mesh8: skipped ({m8['skipped']})")
-    else:
-        print(f"[cstore] segmented mesh8 ({m8['n_shards']} shards): "
-              f"{m8['segmented_s']*1e3:.1f}ms vs single-node "
-              f"{m8['single_node_s']*1e3:.1f}ms = "
-              f"{m8['speedup_vs_single_node']:.2f}x")
 
     # --- compression tier (DESIGN.md §9): real packed footprint + the
     # constrained-cache experiment (packed-resident compressed execution
@@ -508,7 +443,4 @@ def run(report):
 
 
 if __name__ == "__main__":
-    if "--mesh8" in sys.argv:
-        _run_mesh8()
-    else:
-        run(lambda k, v: None)
+    run(lambda k, v: None)

@@ -28,6 +28,9 @@ OUT = ROOT / "results" / "bench"
 
 
 def main() -> None:
+    sys.path.insert(0, str(ROOT / "src"))
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (compression, cstore_queries, distribution,
                             encoded_exec, roofline, serving,
                             tuple_mover_bench)

@@ -39,7 +39,8 @@ timeout "$T_FAST" python -m pytest -q -x -p no:cacheprovider \
     tests/test_faults.py \
     tests/test_serving.py \
     tests/test_kernels_seg_preagg.py \
-    tests/test_kernels_bitunpack.py
+    tests/test_kernels_bitunpack.py \
+    tests/test_tpu_compile.py
 
 echo "== compression tier: packed-exec property tests + 20-query oracle =="
 # bit-packed storage round-trips and the compressed-domain execution path

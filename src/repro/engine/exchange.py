@@ -12,14 +12,41 @@ mesh; the training stack reuses the same pattern for MoE expert dispatch
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+
+def _slots(n_shards: int, per: int, dest_l: jax.Array):
+    """Slot of each local row within its destination bucket, whether it
+    fits (``slot < per``), and the rows per destination that did not.
+
+    One running count per destination over flat (n_local,) vectors -- an
+    (n_local, n_shards) one-hot would pad its minor dim to 128 lanes on
+    TPU.  Overflowing rows get the scratch slot ``per``, sliced off by
+    :func:`_move` -- writing them to per-1 would clobber the legitimate
+    last slot and silently drop one MORE tuple than reported."""
+    pos = jnp.zeros(dest_l.shape, jnp.int32)
+    for d in range(n_shards):
+        hit = (dest_l == d).astype(jnp.int32)
+        pos = jnp.where(dest_l == d, jnp.cumsum(hit) - hit, pos)
+    keep = pos < per
+    dropped = jnp.stack([((dest_l == d) & ~keep).sum(dtype=jnp.int32)
+                         for d in range(n_shards)])
+    return jnp.where(keep, pos, per), keep, dropped
+
+
+def _move(axis: str, n_shards: int, per: int, dest_l: jax.Array,
+          slot: jax.Array, keep: jax.Array, v: jax.Array) -> jax.Array:
+    """One column to its destination slots, then across the mesh."""
+    buf = jnp.zeros((n_shards, per + 1), v.dtype)
+    buf = buf.at[dest_l, slot].set(jnp.where(keep, v, 0))[:, :per]
+    return jax.lax.all_to_all(buf, axis, 0, 0, tiled=False).reshape(-1)
 
 
 def resegment_local(axis: str, n_shards: int, per: int, dest_l: jax.Array,
@@ -32,30 +59,34 @@ def resegment_local(axis: str, n_shards: int, per: int, dest_l: jax.Array,
     exchange and the join).  ``dest_l`` is the (n_local,) destination
     shard per local row; returns (moved value tuple, valid, overflow),
     each moved value flat with ``n_shards * per`` slots."""
-    n_local = dest_l.shape[0]
-    # slot of each row within its destination bucket
-    onehot = jax.nn.one_hot(dest_l, n_shards, dtype=jnp.int32)
-    pos = (jnp.cumsum(onehot, axis=0) - onehot)[
-        jnp.arange(n_local), dest_l]
-    keep = pos < per
-    # rows this source shard wanted to send to each destination but
-    # could not fit; global per-destination overflow is the psum
-    dropped = (onehot * (~keep)[:, None].astype(jnp.int32)).sum(axis=0)
+    slot, keep, dropped = _slots(n_shards, per, dest_l)
+    # global per-destination overflow is the psum of what each source
+    # wanted to send but could not fit
     overflow = jax.lax.psum(dropped, axis)
-    # overflowing rows write to a scratch column (per) that is sliced
-    # off -- writing them to per-1 would clobber the legitimate last
-    # slot and silently drop one MORE tuple than reported
-    slot = jnp.where(keep, pos, per)
-    out_valid = jnp.zeros((n_shards, per + 1), jnp.bool_)
-    out_valid = out_valid.at[dest_l, slot].set(keep)[:, :per]
-    outs = []
-    for v in vals:
-        buf = jnp.zeros((n_shards, per + 1), v.dtype)
-        buf = buf.at[dest_l, slot].set(
-            jnp.where(keep, v, 0))[:, :per]
-        outs.append(jax.lax.all_to_all(buf, axis, 0, 0, tiled=False))
-    vr = jax.lax.all_to_all(out_valid, axis, 0, 0, tiled=False)
-    return (tuple(o.reshape(-1) for o in outs), vr.reshape(-1), overflow)
+    outs = tuple(_move(axis, n_shards, per, dest_l, slot, keep, v)
+                 for v in vals)
+    vr = _move(axis, n_shards, per, dest_l, slot, keep, keep)
+    return outs, vr, overflow
+
+
+@lru_cache(maxsize=32)
+def _resegment_programs(mesh: Mesh, axis: str, per: int):
+    """(slots, move) programs for :func:`resegment`.  The move program is
+    per column, so its compiled executable is shared by every column and
+    every call of one dtype and row count -- at tens of millions of rows
+    each distinct program costs tens of seconds to compile for TPU."""
+    n_shards = mesh.shape[axis]
+
+    def slots(dest_l):
+        slot, keep, dropped = _slots(n_shards, per, dest_l)
+        return slot, keep, jax.lax.psum(dropped, axis)
+
+    slots_fn = jax.jit(shard_map(slots, mesh=mesh, in_specs=P(axis),
+                                 out_specs=(P(axis), P(axis), P())))
+    move_fn = jax.jit(shard_map(partial(_move, axis, n_shards, per),
+                                mesh=mesh, in_specs=(P(axis),) * 4,
+                                out_specs=P(axis)))
+    return slots_fn, move_fn
 
 
 def resegment(mesh: Mesh, axis: str, cols: Dict[str, jax.Array],
@@ -70,20 +101,13 @@ def resegment(mesh: Mesh, axis: str, cols: Dict[str, jax.Array],
     retry with a larger capacity or fail loudly; silent truncation is a
     wrong answer, not a slow one.  One all_to_all per column -- each tuple
     crosses the wire exactly once."""
-    n_shards = mesh.shape[axis]
-
-    def local(dest_l, *vals):
-        outs, vr, overflow = resegment_local(
-            axis, n_shards, capacity // n_shards, dest_l, vals)
-        return outs + (vr, overflow)
-
-    names = list(cols)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(axis),) * (1 + len(names)),
-                   out_specs=(P(axis),) * (len(names) + 1) + (P(),))
-    res = fn(dest, *[cols[c] for c in names])
-    out = dict(zip(names, res[:-2]))
-    return out, res[-2], res[-1]
+    slots_fn, move_fn = _resegment_programs(
+        mesh, axis, capacity // mesh.shape[axis])
+    slot, keep, overflow = slots_fn(dest)
+    out = {c: move_fn(dest, slot, keep, v) for c, v in cols.items()}
+    # validity rides as bytes, sharing the int8 program with byte columns
+    valid = move_fn(dest, slot, keep, keep.astype(jnp.int8)) != 0
+    return out, valid, overflow
 
 
 def broadcast_build_side(mesh: Mesh, axis: str,
@@ -93,9 +117,9 @@ def broadcast_build_side(mesh: Mesh, axis: str,
         return tuple(jax.lax.all_gather(v, axis, tiled=True) for v in vals)
 
     names = list(cols)
-    # check_rep=False: all_gather(tiled) output IS replicated, but the
-    # static replication checker cannot infer it on every jax version
+    # check_vma=False: all_gather(tiled) output IS replicated, but the
+    # out_specs of P() assert it without the checker's proof
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(axis),) * len(names),
-                   out_specs=(P(),) * len(names), check_rep=False)
+                   out_specs=(P(),) * len(names), check_vma=False)
     return dict(zip(names, fn(*[cols[c] for c in names])))

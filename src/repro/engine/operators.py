@@ -245,15 +245,16 @@ def groupby_rle(key_col: EncodedColumn, valid_counts: np.ndarray,
     §6.1 'operate directly on encoded data' fast path (Pallas twin:
     kernels/rle_scan_agg.py)."""
     assert key_col.encoding == Encoding.RLE
-    if jax.default_backend() == "tpu":
+    from ..kernels import ops as kops
+    from ..kernels.rle_scan_agg import DOMAIN_CAP
+    if kops.on_tpu() and domain <= DOMAIN_CAP:
         # fused Pallas path: per-key count straight off the runs (grouped
         # twin of the scalar kernel; CPU stays on the XLA scatter below
         # because interpret-mode Pallas is row-at-a-time Python)
-        from ..kernels import ops as kops
         out = kops.rle_grouped_agg(
             jnp.asarray(key_col.arrays["run_values"]),
             jnp.asarray(key_col.arrays["run_lengths"]), domain=domain)
-        return {"group_count": out[0].astype(_int_dtype())}
+        return {"group_count": out["count"].astype(_int_dtype())}
     rv = jnp.asarray(key_col.arrays["run_values"]).reshape(-1)
     rl = jnp.asarray(key_col.arrays["run_lengths"]).reshape(-1)
     # clamp tail-block padding runs: total rows cap

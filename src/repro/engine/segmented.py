@@ -62,7 +62,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.block_cache import KIND_SEG, KIND_WOS
@@ -70,6 +70,7 @@ from ..core.database import VerticaDB
 from ..core.faults import fire_with_retries
 from ..core.segmentation import (hash_columns, hash_columns_jnp, shard_of,
                                  shard_of_jnp)
+from ..kernels.seg_preagg import interpreted as preagg_interpreted
 from ..kernels.seg_preagg import seg_preagg
 from ..planner import cost as cost_mod
 from . import exchange
@@ -290,41 +291,51 @@ def _build_dest_program(mesh, axis: str, n_shards: int,
     return jax.jit(fn)
 
 
-def _build_compact_program(mesh, axis: str, names: Tuple[str, ...],
-                           dkeys: Tuple[str, ...], per_out: int, sb: int):
-    """Build phase B2: per shard, move valid rows to the front (stable,
-    preserving source container order -- at one shard the slab keeps the
-    exact single-node scan order, so its block SMAs prune at least as
-    tightly), slice to the padded row budget, and compute per-block
-    min/max/count SMAs over the surviving layout."""
+def _build_take_program(mesh, axis: str, per_out: int, sb: int):
+    """Build phase B2a: per shard, the rows to keep -- valid rows first
+    (stable, preserving source container order: at one shard the slab
+    keeps the exact single-node scan order, so its block SMAs prune at
+    least as tightly), sliced to the padded row budget -- and the valid
+    count per block.  Independent of the columns, so one compiled sort
+    serves every query's slab of the same size."""
     nb = per_out // sb
 
-    def local_fn(cols, valid, dests):
+    def local_fn(valid):
         n_local = valid.shape[0]
         # stable valid-first order without argsort-kind kwargs: invalid
         # rows rank after every valid row, ties broken by position
         rank = (jnp.where(valid, 0, 1) * n_local
                 + jnp.arange(n_local, dtype=jnp.int32))
         take = jnp.argsort(rank)[:per_out]
-        out_cols = {c: v[take] for c, v in cols.items()}
         valid_c = valid[take]
-        out_dests = {k: d[take] for k, d in dests.items()}
+        bcount = valid_c.reshape(nb, sb).sum(axis=1).astype(jnp.int32)
+        return take, valid_c, bcount
+
+    fn = shard_map(local_fn, mesh=mesh, in_specs=P(axis),
+                   out_specs=(P(axis),) * 3)
+    return jax.jit(fn)
+
+
+def _build_gather_program(mesh, axis: str, per_out: int, sb: int):
+    """Build phase B2b: one column through the kept rows, with its
+    per-block min/max SMAs over the surviving layout.  Per column, so
+    its compiled executable is shared by every column of one dtype."""
+    nb = per_out // sb
+
+    def local_fn(v, take, valid_c):
+        out = v[take]
+        arr = out.reshape(nb, sb)
         v2 = valid_c.reshape(nb, sb)
-        bcount = v2.sum(axis=1).astype(jnp.int32)
-        bmins, bmaxs = {}, {}
-        for c in names:
-            arr = out_cols[c].reshape(nb, sb)
-            if arr.dtype.kind == "f":
-                hi, lo = jnp.inf, -jnp.inf
-            else:
-                hi = jnp.iinfo(arr.dtype).max
-                lo = jnp.iinfo(arr.dtype).min
-            bmins[c] = jnp.where(v2, arr, hi).min(axis=1)
-            bmaxs[c] = jnp.where(v2, arr, lo).max(axis=1)
-        return out_cols, valid_c, out_dests, bcount, bmins, bmaxs
+        if arr.dtype.kind == "f":
+            hi, lo = jnp.inf, -jnp.inf
+        else:
+            hi = jnp.iinfo(arr.dtype).max
+            lo = jnp.iinfo(arr.dtype).min
+        return (out, jnp.where(v2, arr, hi).min(axis=1),
+                jnp.where(v2, arr, lo).max(axis=1))
 
     fn = shard_map(local_fn, mesh=mesh, in_specs=(P(axis),) * 3,
-                   out_specs=(P(axis),) * 6)
+                   out_specs=(P(axis),) * 3)
     return jax.jit(fn)
 
 
@@ -393,14 +404,17 @@ def _build_ros_slab_device(db: VerticaDB, proj, plan, need: Sequence[str],
               for k in reseg_keys}
 
     per_out = _round_up(max(int(r0_np.max()), 1), _SLAB_BLOCK)
-    names = tuple(sorted(need))
-    fn2, _ = PLAN_CACHE.get_or_build(
-        ("seg-compact", _mesh_sig(mesh, axis), names, reseg_keys,
-         per_out, _SLAB_BLOCK),
-        lambda: _build_compact_program(mesh, axis, names, reseg_keys,
-                                       per_out, _SLAB_BLOCK))
-    cols_c, valid_c, dests_c, bcount, bmins, bmaxs = fn2(
-        {c: moved[c] for c in need}, valid2, dests2)
+    take_fn, _ = PLAN_CACHE.get_or_build(
+        ("seg-take", _mesh_sig(mesh, axis), per_out, _SLAB_BLOCK),
+        lambda: _build_take_program(mesh, axis, per_out, _SLAB_BLOCK))
+    gather_fn, _ = PLAN_CACHE.get_or_build(
+        ("seg-gather", _mesh_sig(mesh, axis), per_out, _SLAB_BLOCK),
+        lambda: _build_gather_program(mesh, axis, per_out, _SLAB_BLOCK))
+    take, valid_c, bcount = take_fn(valid2)
+    cols_c, bmins, bmaxs = {}, {}, {}
+    for c in sorted(need):
+        cols_c[c], bmins[c], bmaxs[c] = gather_fn(moved[c], take, valid_c)
+    dests_c = {k: gather_fn(d, take, valid_c)[0] for k, d in dests2.items()}
 
     nb = per_out // _SLAB_BLOCK
     bcount_np = np.asarray(bcount).reshape(n_shards, nb)
@@ -868,7 +882,7 @@ def _build_stage_factory(mesh, axis: str, n_shards: int, specs: Sequence,
         (ir, algo, domains, lows, domain, local_aggs, values_cols,
          packed) = final_cfg
 
-    def build(per_new: int):
+    def build(per_new: int, check_vma: bool):
         def local_fn(cols, valid, dests, shard_idx, builds):
             cols = dict(cols)
             dests = dict(dests)
@@ -927,15 +941,18 @@ def _build_stage_factory(mesh, axis: str, n_shards: int, specs: Sequence,
         fn = shard_map(local_fn, mesh=mesh,
                        in_specs=(P(axis), P(axis), P(axis), P(axis),
                                  tuple(build_specs)),
-                       out_specs=(P(axis), P()))
+                       out_specs=(P(axis), P()), check_vma=check_vma)
         return jax.jit(fn)
 
-    progs: Dict[int, object] = {}
+    progs: Dict[Tuple[int, bool], object] = {}
 
     def get(per_new: int):
-        fn = progs.get(per_new)
+        # the varying-axes check is off only around an interpret-mode
+        # seg_preagg (see kernels/seg_preagg.interpreted)
+        key = (per_new, not preagg_interpreted())
+        fn = progs.get(key)
         if fn is None:
-            fn = progs[per_new] = build(per_new)
+            fn = progs[key] = build(*key)
         return fn
 
     return get
@@ -1077,7 +1094,7 @@ def execute_segmented(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
 
     # per-stage wall clocks (ExecStats.stage_ms): opt-in because honest
     # stage boundaries need a device sync, which the pipelined normal
-    # path must not pay.  The cstore bench's mesh8 tier flips this on.
+    # path must not pay.  The cstore bench's segmented tier flips this on.
     timing = bool(getattr(db, "collect_stage_timing", False))
 
     def _tick(label: str, t0: float, out) -> float:

@@ -9,17 +9,20 @@ kernel is 32 unrolled shift/mask lanes with static indices, no gather.
 
 Three implementations, dispatched by kernels/ops.py like seg_preagg:
 
-* ``bitunpack_pallas`` -- grid kernel, one (block, 512-row tile) per program,
-  optionally fused with the per-block base-offset add of the delta
-  reconstruction (DELTA_VALUE base / DELTA_RANGE delta_min).
+* ``bitunpack_pallas`` -- grid kernel over word-major tiles (below); the
+  per-block base-offset add of the delta reconstruction (DELTA_VALUE base /
+  DELTA_RANGE delta_min) fuses into the transpose that restores row order.
 * ``bitunpack_xla``    -- shift/mask reference path, byte-identical on CPU.
 * ``gather_unpack``    -- random access: decode only (block, row) positions,
   the late-materialization gather for surviving rows.
 
-TPU tiling note: the words tile's last dim is 16*w for a 512-row tile, a
-multiple of 128 for w in {8, 16, 24, 32}; other widths rely on relayout (or
-interpret mode off-TPU).  Symbols wider than 32 bits never reach here --
-encodings fall back to byte-wide storage.
+TPU layout: the kernel never reshapes in VMEM.  The wrapper transposes the
+packed stream word-major, ``(width, groups)`` -- row k holds word k of
+every 32-symbol group -- and tiles the groups as ``(rows, 128)`` lanes.
+Slot s of every group is then two static sublane-row reads, shifts and a
+mask over full ``(8k, 128)`` tiles, written to output row s; the wrapper
+transposes the ``(32, groups)`` result back to row order.  Symbols wider
+than 32 bits never reach here -- encodings fall back to byte-wide storage.
 """
 from __future__ import annotations
 
@@ -30,9 +33,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-_TILE_ROWS = 512
-
 
 def _mask32(width: int) -> int:
     return (1 << width) - 1 if width < 32 else 0xFFFFFFFF
@@ -69,28 +69,21 @@ def bitunpack_xla(words: jax.Array, width: int, block_rows: int,
     return out
 
 
-def _unpack_block(g: jax.Array, width: int) -> jax.Array:
-    """(rows//32, width) uint32 words -> (rows,) uint32 symbols, unrolled."""
+_TILE_SUBLANES = 32      # (32, 128) groups = 131072 symbols per grid step
+
+
+def _kernel(words_ref, out_ref, *, width):
+    """words (width, ts, 128): word k of each group in row k ->
+    out (32, ts, 128): slot s of each group in row s, unrolled."""
     lo, sh, hi, hi_shift, straddle = _slot_tables(width)
     mask = jnp.uint32(_mask32(width))
-    cols = []
     for s in range(32):
-        v = g[:, lo[s]] >> jnp.uint32(sh[s])
+        v = words_ref[int(lo[s])] >> jnp.uint32(sh[s])
         if straddle[s]:
-            v = v | (g[:, hi[s]] << jnp.uint32(hi_shift[s]))
-        cols.append(v & mask)
-    return jnp.stack(cols, axis=1).reshape(-1)
-
-
-def _kernel(words_ref, out_ref, *, width, rows):
-    g = words_ref[...].reshape(rows // 32, width)
-    out_ref[...] = _unpack_block(g, width).astype(jnp.int32)[None, :]
-
-
-def _kernel_base(base_ref, words_ref, out_ref, *, width, rows):
-    g = words_ref[...].reshape(rows // 32, width)
-    syms = _unpack_block(g, width).astype(jnp.int32)[None, :]
-    out_ref[...] = syms + base_ref[...].astype(jnp.int32)
+            v = v | (words_ref[int(hi[s])] << jnp.uint32(hi_shift[s]))
+        if width < 32:
+            v = v & mask
+        out_ref[s] = v.astype(jnp.int32)
 
 
 @functools.partial(jax.jit,
@@ -98,34 +91,33 @@ def _kernel_base(base_ref, words_ref, out_ref, *, width, rows):
 def bitunpack_pallas(words: jax.Array, width: int, block_rows: int,
                      base: Optional[jax.Array] = None, *,
                      interpret: bool = False) -> jax.Array:
-    """Pallas grid unpack, fused with the per-block base add when given."""
+    """Pallas unpack: words (nb, ng*width) uint32 -> (nb, block_rows)
+    int32; ``base`` (nb,) is added per block when given."""
     nb, nw = words.shape
     ng = nw // width
-    rows_padded = ng * 32
-    tile = _TILE_ROWS if rows_padded % _TILE_ROWS == 0 else rows_padded
-    nt = rows_padded // tile
-    tile_words = (tile // 32) * width
-    word_spec = pl.BlockSpec((1, tile_words), lambda i, j: (i, j))
-    out_spec = pl.BlockSpec((1, tile), lambda i, j: (i, j))
-    if base is None:
-        out = pl.pallas_call(
-            functools.partial(_kernel, width=width, rows=tile),
-            grid=(nb, nt),
-            in_specs=[word_spec],
-            out_specs=out_spec,
-            out_shape=jax.ShapeDtypeStruct((nb, rows_padded), jnp.int32),
-            interpret=interpret,
-        )(words)
-    else:
-        out = pl.pallas_call(
-            functools.partial(_kernel_base, width=width, rows=tile),
-            grid=(nb, nt),
-            in_specs=[pl.BlockSpec((1, 1), lambda i, j: (i, 0)), word_spec],
-            out_specs=out_spec,
-            out_shape=jax.ShapeDtypeStruct((nb, rows_padded), jnp.int32),
-            interpret=interpret,
-        )(base.reshape(nb, 1).astype(jnp.int32), words)
-    return out[:, :block_rows]
+    n_groups = nb * ng
+    rows = -(-n_groups // 128)
+    ts = min(_TILE_SUBLANES, -(-rows // 8) * 8)
+    rows_p = -(-rows // ts) * ts
+    wt = words.reshape(nb, ng, width).transpose(2, 0, 1) \
+        .reshape(width, n_groups)
+    wt = jnp.pad(wt, ((0, 0), (0, rows_p * 128 - n_groups))) \
+        .reshape(width, rows_p, 128)
+    out = pl.pallas_call(
+        functools.partial(_kernel, width=width),
+        grid=(rows_p // ts,),
+        in_specs=[pl.BlockSpec((width, ts, 128), lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec((32, ts, 128), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((32, rows_p, 128), jnp.int32),
+        interpret=interpret,
+        name="bitunpack",
+    )(wt)
+    out = out.reshape(32, rows_p * 128)[:, :n_groups] \
+        .reshape(32, nb, ng).transpose(1, 2, 0) \
+        .reshape(nb, ng * 32)[:, :block_rows]
+    if base is not None:
+        out = out + base[:, None].astype(jnp.int32)
+    return out
 
 
 def gather_unpack(words: jax.Array, width: int, b_idx: jax.Array,

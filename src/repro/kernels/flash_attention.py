@@ -32,10 +32,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, causal: bool):
 
     def body(j, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (pl.dslice(j * bk, bk), slice(None))
-                    ).astype(jnp.float32)              # (bk, d)
-        v = pl.load(v_ref, (pl.dslice(j * bk, bk), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)   # (bk, d)
+        v = v_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = (q @ k.T) * scale                          # (bq, bk)
         if causal:
             qpos = qi * bq + jax.lax.broadcasted_iota(
@@ -81,4 +79,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((bq, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((S, d), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

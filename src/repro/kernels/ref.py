@@ -24,24 +24,25 @@ def rle_filter_agg_ref(run_values: jax.Array, run_lengths: jax.Array,
 
 def rle_grouped_agg_ref(run_values: jax.Array, run_lengths: jax.Array,
                         values: jax.Array, domain: int,
-                        lo: float, hi: float) -> jax.Array:
-    """Per-key (count, sum, min, max) over a dense domain, from RLE runs:
+                        lo: float, hi: float) -> dict:
+    """Per-key count / sum / min / max over a dense domain, from RLE runs:
     a run of key k and length L contributes L rows of its value.  Runs
     with key outside [lo, hi] (or [0, domain)) or zero length drop out.
-    Returns (4, domain) f32; empty keys: count 0, sum 0, min/max at the
-    +-3.4e38 sentinels (matching the Pallas kernel)."""
+    Returns {"count": (domain,) int32, "sum"/"min"/"max": (domain,) f32};
+    empty keys: count 0, sum 0, min/max at the +-3.4e38 sentinels
+    (matching the Pallas kernel)."""
     rv = run_values.astype(jnp.float32).reshape(-1)
-    rl = run_lengths.astype(jnp.float32).reshape(-1)
+    rl = run_lengths.astype(jnp.int32).reshape(-1)
     val = values.astype(jnp.float32).reshape(-1)
     m = (rv >= lo) & (rv <= hi) & (rl > 0) & (rv >= 0) & (rv < domain)
     k = jnp.clip(run_values.astype(jnp.int32).reshape(-1), 0, domain - 1)
-    mf = m.astype(jnp.float32)
-    cnt = jnp.zeros(domain, jnp.float32).at[k].add(rl * mf)
-    s = jnp.zeros(domain, jnp.float32).at[k].add(val * rl * mf)
+    cnt = jnp.zeros(domain, jnp.int32).at[k].add(jnp.where(m, rl, 0))
+    s = jnp.zeros(domain, jnp.float32).at[k].add(
+        jnp.where(m, val * rl.astype(jnp.float32), 0.0))
     pos, neg = jnp.float32(3.4e38), jnp.float32(-3.4e38)
     mn = jnp.full(domain, pos).at[k].min(jnp.where(m, val, pos))
     mx = jnp.full(domain, neg).at[k].max(jnp.where(m, val, neg))
-    return jnp.stack([cnt, s, mn, mx], axis=0)
+    return {"count": cnt, "sum": s, "min": mn, "max": mx}
 
 
 def seg_preagg_ref(keys: jax.Array, valid: jax.Array, values,
@@ -91,7 +92,9 @@ def onehot_groupby_ref(keys: jax.Array, values: jax.Array,
     keys (nb, B) int32, values (nb, B) f32 -> (nb, domain, 2) f32."""
     onehot = jax.nn.one_hot(keys, domain, dtype=jnp.float32)  # (nb,B,dom)
     cnt = onehot.sum(axis=1)
-    s = jnp.einsum("nbd,nb->nd", onehot, values.astype(jnp.float32))
+    # HIGHEST: on TPU a default-precision f32 einsum rounds to bf16
+    s = jnp.einsum("nbd,nb->nd", onehot, values.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
     return jnp.stack([cnt, s], axis=-1)
 
 
@@ -137,10 +140,13 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = True) -> jax.Array:
     """q (S, d), k/v (T, d) -> (S, d); fp32 softmax."""
     d = q.shape[-1]
-    s = (q.astype(jnp.float32) @ k.astype(jnp.float32).T) / np.sqrt(d)
+    hp = jax.lax.Precision.HIGHEST   # f32 on TPU too, not bf16 passes
+    s = jnp.matmul(q.astype(jnp.float32), k.astype(jnp.float32).T,
+                   precision=hp) / np.sqrt(d)
     if causal:
         S, T = s.shape
         mask = jnp.arange(S)[:, None] >= jnp.arange(T)[None, :]
         s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    return (p @ v.astype(jnp.float32)).astype(q.dtype)
+    return jnp.matmul(p, v.astype(jnp.float32),
+                      precision=hp).astype(q.dtype)

@@ -1,8 +1,9 @@
 """SIP semi-join probe Pallas kernel (§6.1 Sideways Information Passing).
 
 The join build side (small dimension keys, padded to a lane multiple) sits
-in VMEM; each grid step tests one block of probe keys against all of it
-with a broadcast compare + any-reduce. Exact (not Bloom): on TPU the
+in VMEM as one column; each grid step tests one block of probe keys, 128
+lanes at a time, against all of it with a broadcast compare + any-reduce over
+sublanes. Exact (not Bloom): on TPU the
 build side fits VMEM wholesale, so the approximate filter is unnecessary --
 an intentional deviation recorded in DESIGN.md.
 """
@@ -17,11 +18,17 @@ from jax.experimental import pallas as pl
 MAX_BUILD = 4096   # keys; 16KB of VMEM
 
 
-def _kernel(keys_ref, build_ref, out_ref):
-    k = keys_ref[...]                                  # (1, B)
-    b = build_ref[...]                                 # (1, S)
-    eq = k.reshape(-1, 1) == b.reshape(1, -1)          # (B, S)
-    out_ref[...] = eq.any(axis=1).reshape(1, -1)
+def _kernel(keys_ref, build_ref, out_ref, *, chunk: int):
+    b = build_ref[...]                                 # (S, 1)
+
+    def probe(c, carry):
+        k = keys_ref[0, :, pl.ds(c * chunk, chunk)]    # (1, chunk)
+        eq = (b == k).astype(jnp.int32)                # (S, chunk)
+        out_ref[0, :, pl.ds(c * chunk, chunk)] = eq.max(axis=0,
+                                                        keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, keys_ref.shape[2] // chunk, probe, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -35,14 +42,18 @@ def semijoin_probe(keys: jax.Array, build: jax.Array, *,
     if pad:
         build = jnp.pad(build, (0, pad), constant_values=-1)
         S += pad
-    return pl.pallas_call(
-        _kernel,
+    chunk = 128 if B % 128 == 0 else B
+    out = pl.pallas_call(
+        functools.partial(_kernel, chunk=chunk),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, S), lambda i: (0, 0)),
+            pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
+            pl.BlockSpec((S, 1), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, B), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, B), jnp.bool_),
+        out_specs=pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, B), jnp.int32),
         interpret=interpret,
-    )(keys.astype(jnp.int32), build.astype(jnp.int32).reshape(1, -1))
+        name="semijoin_probe",
+    )(keys.astype(jnp.int32).reshape(nb, 1, B),
+      build.astype(jnp.int32).reshape(S, 1))
+    return out.reshape(nb, B) != 0

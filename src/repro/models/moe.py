@@ -134,7 +134,7 @@ def moe_apply_expert_local(p, x: jax.Array, moe: MoEConfig, rules, mesh
       experts : expert dim sharded over 'model', d dim sharded over 'data'
                 (FSDP storage) and all-gathered here, explicitly.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     names = mesh.axis_names
@@ -217,6 +217,6 @@ def moe_apply_expert_local(p, x: jax.Array, moe: MoEConfig, rules, mesh
         in_specs=(P(), P("model", "data"), P("model", "data"),
                   P("model", None, "data"), P(dp_axes)),
         out_specs=(P(dp_axes), P()),
-        check_rep=False)
+        check_vma=False)
     out, aux = fn(p["router"], p["wi_gate"], p["wi_up"], p["wo"], x)
     return out, aux * moe.router_aux_coef

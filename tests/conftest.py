@@ -2,12 +2,12 @@
 only launch/dryrun.py requests 512 placeholder devices (assignment rule)."""
 import numpy as np
 import pytest
+from hypothesis import settings
 
-try:                       # property tests prefer the real hypothesis;
-    import hypothesis      # noqa: F401
-except ImportError:        # image without it: a deterministic mini-shim
-    from repro._compat import install_hypothesis_stub
-    install_hypothesis_stub()
+# a property test's first example traces and compiles its programs, which
+# takes seconds; the per-example deadline would count that as the test's
+settings.register_profile("repro", deadline=None)
+settings.load_profile("repro")
 
 from repro.core import ColumnDef, SQLType, TableSchema, VerticaDB
 

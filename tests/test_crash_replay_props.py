@@ -11,9 +11,6 @@ Comparisons are exact: raw snapshot reads compare as sorted tuple sets
 (identical values, container layout may legally differ), and aggregate
 queries restrict to integer columns so no float summation order can
 differ.
-
-Runs under the real ``hypothesis`` when installed, else the
-deterministic mini-shim (repro/_compat, installed by conftest.py).
 """
 import numpy as np
 from hypothesis import given, settings

@@ -33,8 +33,11 @@ def test_rle_grouped_agg(nb, R, domain, bounded):
     lo, hi = (2.0, float(domain)) if bounded else (-3.0e38, 3.0e38)
     got = ops.rle_grouped_agg(rv, rl, val, domain=domain, lo=lo, hi=hi)
     want = ref.rle_grouped_agg_ref(rv, rl, val, domain, lo, hi)
-    assert got.shape == (4, domain)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    assert got["count"].shape == (domain,)
+    assert got["count"].dtype == jnp.int32
+    np.testing.assert_array_equal(got["count"], want["count"])
+    for k in ("sum", "min", "max"):
+        np.testing.assert_allclose(got[k], want[k], rtol=1e-4, atol=1e-4)
 
 
 def test_rle_grouped_agg_default_values_is_key():
@@ -42,11 +45,24 @@ def test_rle_grouped_agg_default_values_is_key():
     rl = jnp.asarray(RNG.integers(0, 5, (2, 128)), jnp.int32)
     got = ops.rle_grouped_agg(rv, rl, domain=8)
     want = ref.rle_grouped_agg_ref(rv, rl, rv, 8, -3.0e38, 3.0e38)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    for k in ("count", "sum", "min", "max"):
+        np.testing.assert_allclose(got[k], want[k], rtol=1e-4, atol=1e-4)
     # count of key k == total run length with that key
     flat_rv, flat_rl = np.asarray(rv).ravel(), np.asarray(rl).ravel()
     for k in range(8):
-        assert got[0, k] == flat_rl[flat_rv == k].sum()
+        assert got["count"][k] == flat_rl[flat_rv == k].sum()
+
+
+def test_rle_grouped_agg_long_runs_exact():
+    """Counts stay exact for run lengths far above bf16's 256-integer
+    range (a block-long run of one key is the common sorted case)."""
+    rv = jnp.asarray(RNG.integers(0, 365, (64, 128)), jnp.int32)
+    rl = jnp.asarray(RNG.integers(4000, 4097, (64, 128)), jnp.int32)
+    got = ops.rle_grouped_agg(rv, rl, domain=365)
+    want = np.bincount(np.asarray(rv).ravel(),
+                       weights=np.asarray(rl).ravel(), minlength=365)
+    np.testing.assert_array_equal(np.asarray(got["count"]),
+                                  want.astype(np.int64))
 
 
 @pytest.mark.parametrize("nb,B,domain", [(1, 128, 16), (4, 256, 64),

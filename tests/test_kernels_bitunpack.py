@@ -105,3 +105,16 @@ def test_delta_decode_oracle_both_paths(nb, B, dtype, env, monkeypatch):
     got = ops.delta_decode(first, deltas)
     want = ref.delta_decode_ref(first, deltas)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("env", ["", "xla", "pallas"])
+def test_tpu_dispatch_ignores_env(env, monkeypatch):
+    """On a TPU the decode kernels and seg_preagg always take the compiled
+    kernel: no REPRO_* value selects interpret mode or the XLA path."""
+    from repro.kernels import seg_preagg
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    for var in ("REPRO_BITUNPACK", "REPRO_DELTA_DECODE",
+                "REPRO_SEG_PREAGG"):
+        monkeypatch.setenv(var, env)
+        assert ops.pallas_enabled(var)
+    assert seg_preagg._use_kernel(64, ("count", "sum"))

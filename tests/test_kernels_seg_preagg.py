@@ -84,7 +84,7 @@ def test_all_invalid_rows_yield_sentinels():
 def test_dispatch_declines_large_domains_and_cpu():
     # near-int32 packed domains can never fit the kernel's VMEM budget
     assert not sp._use_kernel(2**31 - 10, ("count", "sum"))
-    assert not sp._use_kernel(sp._DOMAIN_CAP + 1, ("count",))
+    assert not sp._use_kernel(sp.DOMAIN_CAP + 1, ("count",))
     # CPU without the env override keeps the XLA scatter
     assert not sp._use_kernel(64, ("count", "sum"))
     # unsupported aggregate kinds always decline
@@ -109,7 +109,7 @@ def test_large_domain_falls_back_and_matches():
     """Past the VMEM domain cap the dispatcher must keep the XLA scatter
     and still produce groupby_dense's exact outputs."""
     rng = np.random.default_rng(12)
-    domain = sp._DOMAIN_CAP * 4
+    domain = sp.DOMAIN_CAP * 4
     keys, valid, values = _mkdata(rng, 2048, domain)
     got = sp.seg_preagg(keys, valid, values, domain, AGGS)
     want = ops.groupby_dense(keys, valid, values, domain, AGGS)

@@ -3,8 +3,8 @@ compressed-domain execution.
 
 Two guarantees are under test (DESIGN.md §9):
 
-1.  **Packing is lossless** -- property tests (real hypothesis or the
-    deterministic shim) round-trip every width 1..32, negative values,
+1.  **Packing is lossless** -- property tests (hypothesis) round-trip
+    every width 1..32, negative values,
     empty inputs and FLOAT_SCALED through the actual packed word streams.
 
 2.  **Code-domain execution is byte-identical** -- a 20-query seeded

@@ -1,9 +1,6 @@
 """Property-style tests for the segmentation primitives that the
 segmented executor's correctness rests on: ring hashing, buddy-offset
 placement, mixed-radix key packing, and elastic rebalance coverage.
-
-Runs under the real ``hypothesis`` when installed, else the deterministic
-mini-shim (repro/_compat, installed by conftest.py).
 """
 import numpy as np
 import pytest

@@ -577,6 +577,38 @@ def test_segmented_pruning_differential(star_db):
     assert_match(ref, out, ordered=False, label="pruned-range")
 
 
+@pytest.mark.parametrize("forced", [False, True],
+                         ids=["xla-preagg", "interpreted-kernel"])
+def test_stage_checks_varying_axes_unless_kernel_interpreted(
+        star_db, monkeypatch, forced):
+    """The fused stage shard_map keeps jax's varying-axes check on; only
+    around the interpret-mode seg_preagg (REPRO_SEG_PREAGG=pallas off
+    TPU), whose loop discharge drops varying axes, is it turned off."""
+    from repro.engine import segmented as seg
+    from repro.engine.executor import PLAN_CACHE
+
+    built = []
+
+    def recording_shard_map(*args, **kwargs):
+        if "check_vma" in kwargs:
+            built.append(kwargs["check_vma"])
+        return jax.shard_map(*args, **kwargs)
+
+    if forced:
+        monkeypatch.setenv("REPRO_SEG_PREAGG", "pallas")
+    monkeypatch.setattr(seg, "shard_map", recording_shard_map)
+    PLAN_CACHE.clear()
+    db = star_db
+    qb = (db.query("sales").where(col("qty") > 7)
+          .join("parts", on=("partkey", "p_partkey"), cols=("p_cat",))
+          .group_by("p_cat").agg(n=("*", "count"), s=("qty", "sum")))
+    ref, out, stats = run_both(db, qb)
+    PLAN_CACHE.clear()
+    assert stats.segmented
+    assert built and all(v is not forced for v in built), built
+    assert_match(ref, out, ordered=False, label=f"vma-forced={forced}")
+
+
 # ---------------------------------------------------------------------------
 # shard-index-column cache: bounded, oldest-first eviction
 # ---------------------------------------------------------------------------
