@@ -369,22 +369,11 @@ def run(report):
         ts = _time(run_seg)
         seg_all &= last["stats"].segmented
         seg_total += ts
-    # per-stage wall clocks (ExecStats.stage_ms): one extra warm pass per
-    # query with stage syncs enabled -- the timed loop above stays
-    # sync-free so stage accounting never distorts the headline number
-    stage_ms = {}
-    db.collect_stage_timing = True
-    for name in seg_names:
-        _, st = execute(db, QUERIES[name])
-        for k, v in st.stage_ms.items():
-            stage_ms[k] = stage_ms.get(k, 0.0) + v
-    db.collect_stage_timing = False
     db.detach_mesh()
     single_total = sum(rows[n]["vertica_ms"] for n in seg_names) / 1e3
     seg_row = {"n_shards": n_shards, "queries": list(seg_names),
                "segmented_s": seg_total, "single_node_s": single_total,
                "speedup_vs_single_node": single_total / seg_total,
-               "stage_ms": {k: round(v, 2) for k, v in stage_ms.items()},
                "all_segmented": bool(seg_all)}
     print(f"[cstore] segmented ({n_shards} shard(s)): "
           f"{seg_total*1e3:.1f}ms vs single-node "

@@ -4,9 +4,9 @@ The paper's §3-§5 as a library: see DESIGN.md for the architecture map.
 """
 from .block_cache import BlockCache, CacheStats
 from .catalog import Catalog
-from .database import (AvailabilityError, NodeState, QueryRejectedError,
-                       RecoverySourceLostError, SegmentUnavailableError,
-                       Txn, VerticaDB)
+from .database import (AvailabilityError, LoadStats, NodeState,
+                       QueryRejectedError, RecoverySourceLostError,
+                       SegmentUnavailableError, Txn, VerticaDB)
 from .encodings import (EncodedColumn, Encoding, decode_jnp, device_bytes,
                         encode, upload_jnp)
 from .epochs import EpochManager
@@ -29,7 +29,8 @@ __all__ = [
     "ColumnDef", "ColumnSMA", "CrashNode", "DeleteVector", "EncodedColumn",
     "Encoding", "EpochManager", "FaultError", "FaultInjector",
     "INJECTION_POINTS",
-    "FaultTimeout", "Hang", "LockError", "LockManager", "MODES",
+    "FaultTimeout", "Hang", "LoadStats", "LockError", "LockManager",
+    "MODES",
     "NodeCrashError", "NodeState", "NullInjector", "PrejoinSpec",
     "ProjectionDef", "ProjectionStore", "QueryRejectedError",
     "ROSContainer", "RecoverySourceLostError", "SQLType",

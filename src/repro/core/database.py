@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -37,6 +38,44 @@ from .tuple_mover import (ProjectionStore, mergeout, moveout,
 from .types import SQLType, TableSchema
 
 _txn_ids = itertools.count(1)
+
+
+@dataclasses.dataclass
+class LoadStats:
+    """Host seconds a database spends loading, by phase.
+
+    ``rows`` counts the rows of committed inserts and ``load_s`` the wall
+    time of ``insert`` and ``commit``.  The phases are charged where they
+    run: ``segment_s`` in ``insert`` (rows to nodes); ``sort_s`` (the
+    projection's sort order), ``encode_s`` (by the encoding the projection
+    asks for, so AUTO holds its trial encodes) and ``sma_s`` (per-block
+    min/max) in a direct-to-ROS commit's moveout.  A WOS load leaves
+    those to the tuple mover, which is not load time.  Every database's
+    loads also add into the process's ``PROCESS_LOAD``."""
+
+    rows: int = 0
+    load_s: float = 0.0
+    segment_s: float = 0.0
+    sort_s: float = 0.0
+    sma_s: float = 0.0
+    encode_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    parent: Optional["LoadStats"] = dataclasses.field(
+        default=None, repr=False, compare=False)
+
+    def add(self, phase: str, value: float, encoding: str = "") -> None:
+        """``value`` onto ``phase`` (``encode_s`` per ``encoding``), here
+        and in the parent."""
+        s = self
+        while s is not None:
+            if encoding:
+                s.encode_s[encoding] = s.encode_s.get(encoding, 0.0) + value
+            else:
+                setattr(s, phase, getattr(s, phase) + value)
+            s = s.parent
+
+
+# every database of the process together
+PROCESS_LOAD = LoadStats()
 
 
 class AvailabilityError(Exception):
@@ -143,6 +182,7 @@ class Txn:
     deletes: List[Tuple[str, Callable]] = dataclasses.field(
         default_factory=list)
     direct_to_ros: bool = False
+    rows: int = 0                   # rows inserted, counted at commit
 
 
 class VerticaDB:
@@ -174,6 +214,7 @@ class VerticaDB:
         # bounded mid-query failover budget (engine/pipeline.py): how many
         # node-crash replans a single query absorbs before rejecting
         self.max_failover_retries = 2
+        self.load_stats = LoadStats(parent=PROCESS_LOAD)
 
     # ------------------------------------------------------------- DDL --
 
@@ -285,8 +326,10 @@ class VerticaDB:
 
     def insert(self, txn: Txn, table: str, data: Dict[str, np.ndarray]):
         """Stage rows for every projection of the table (lock mode I)."""
+        t0 = time.perf_counter()
         self.locks.acquire(table, txn.id, "I")
         n = len(next(iter(data.values())))
+        txn.rows += n
         for proj in self.catalog.projections_of(table):
             pdata = self._project_rows(proj, data)
             if proj.segmentation.replicated:
@@ -297,12 +340,15 @@ class VerticaDB:
                     self._stage(txn, proj.name, node_id, pdata, sel_all,
                                 segs, None)
             else:
+                ts = time.perf_counter()
                 nodes, segs, ring = proj.segmentation.place_with_ring(
                     pdata, self.catalog.n_nodes)
+                self.load_stats.add("segment_s", time.perf_counter() - ts)
                 for node_id in np.unique(nodes):
                     sel = nodes == node_id
                     self._stage(txn, proj.name, int(node_id), pdata, sel,
                                 segs[sel], ring[sel])
+        self.load_stats.add("load_s", time.perf_counter() - t0)
 
     def _project_rows(self, proj: ProjectionDef,
                       data: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
@@ -363,6 +409,7 @@ class VerticaDB:
                = ()) -> int:
         """Quorum commit without 2PC. Nodes failing mid-commit are ejected
         and must recover; the commit succeeds iff a quorum remains."""
+        t0 = time.perf_counter()
         for nid in fail_nodes_during_commit:
             self.fail_node(nid)
         up = [n for n in self.nodes if n.up]
@@ -438,6 +485,8 @@ class VerticaDB:
             for (proj_name, node_id) in txn.staged:
                 prewarm_wos_buffer(self, node_id, proj_name)
         self.locks.release_all(txn.id)
+        self.load_stats.add("rows", txn.rows)
+        self.load_stats.add("load_s", time.perf_counter() - t0)
         return epoch
 
     def _staged_segments_without_live_copy(self, txn: Txn):
@@ -493,7 +542,7 @@ class VerticaDB:
         new = moveout(tmp, sql_types=self._sql_types(store.proj),
                       ahm=self.epochs.ahm,
                       partition_expr=entry.partition_expr,
-                      block_rows=self.block_rows)
+                      block_rows=self.block_rows, load=self.load_stats)
         store.containers.extend(new)
         for c in new:
             if c.id in tmp.delete_vectors:

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -44,8 +45,10 @@ class ROSContainer:
               epochs: np.ndarray, *, sql_types: Dict[str, SQLType],
               partition_key: Optional[int] = None, local_segment: int = 0,
               presorted: bool = False,
-              block_rows: int = BLOCK_ROWS) -> "ROSContainer":
-        """Sort by the projection's sort order and encode every column."""
+              block_rows: int = BLOCK_ROWS, load=None) -> "ROSContainer":
+        """Sort by the projection's sort order and encode every column;
+        ``load`` (a ``database.LoadStats``) is charged the encode and SMA
+        seconds."""
         n = len(epochs)
         if n and not presorted and proj.sort_order:
             order = np.lexsort(tuple(data[c] for c in
@@ -55,9 +58,15 @@ class ROSContainer:
         cols, smas = {}, {}
         for c in proj.columns:
             v = data[c]
-            cols[c] = encode(v, sql_types.get(c, SQLType.INT),
-                             proj.encoding_for(c), block_rows=block_rows)
+            enc = proj.encoding_for(c)
+            t0 = time.perf_counter()
+            cols[c] = encode(v, sql_types.get(c, SQLType.INT), enc,
+                             block_rows=block_rows)
+            t1 = time.perf_counter()
             smas[c] = ColumnSMA.build(v, block_rows)
+            if load is not None:
+                load.add("encode_s", t1 - t0, encoding=enc.name)
+                load.add("sma_s", time.perf_counter() - t1)
         return ROSContainer(next(_next_container_id), proj.name, cols, smas,
                             np.asarray(epochs, np.int64), n,
                             partition_key, local_segment)

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -143,11 +144,13 @@ class ProjectionStore:
 def moveout(store: ProjectionStore, *, sql_types: Dict[str, SQLType],
             ahm: int, partition_of: Optional[Dict[str, np.ndarray]] = None,
             partition_expr=None,
-            block_rows: int = 4096) -> List[ROSContainer]:
+            block_rows: int = 4096, load=None) -> List[ROSContainer]:
     """Drain the WOS into ROS containers. Returns the new containers.
 
     Rows already deleted at epochs <= AHM are elided; later-deleted rows are
-    written with a delete vector so historical queries still see them."""
+    written with a delete vector so historical queries still see them.
+    ``load`` (a ``database.LoadStats``) is charged the sort, encode and
+    SMA seconds of a load's moveout."""
     data, epochs, segs = store.wos.snapshot()
     if len(epochs) == 0:
         return []
@@ -176,15 +179,18 @@ def moveout(store: ProjectionStore, *, sql_types: Dict[str, SQLType],
             sub_eps, sub_del = epochs[sel], del_eps[sel]
             # sort now so we can map delete epochs to sorted positions
             if store.proj.sort_order:
+                ts = time.perf_counter()
                 order = np.lexsort(tuple(sub[c] for c in
                                          reversed(store.proj.sort_order)))
                 sub = {c: v[order] for c, v in sub.items()}
                 sub_eps, sub_del = sub_eps[order], sub_del[order]
+                if load is not None:
+                    load.add("sort_s", time.perf_counter() - ts)
             c = ROSContainer.build(
                 store.proj, sub, sub_eps, sql_types=sql_types,
                 partition_key=None if pv is None else int(pv),
                 local_segment=int(seg), presorted=True,
-                block_rows=block_rows)
+                block_rows=block_rows, load=load)
             store.containers.append(c)
             new.append(c)
             dpos = np.flatnonzero(sub_del > 0)

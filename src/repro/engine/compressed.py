@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.block_cache import KIND_DECODED, KIND_ENCODED
 from ..core.encodings import (Encoding, EncodedColumn, _packed_width,
                               decode_jnp, device_bytes, gather_decode_jnp,
@@ -128,108 +129,111 @@ class CompressedScanPlan:
         pruned = total = 0
         # (container, kept_idx, device mask, block_rows, encs, tmps)
         segs = []
-        for store, c in self.containers:
-            first = c.columns[self.need[0]]
-            nb, br = first.n_blocks, first.block_rows
-            total += nb
-            # identical SMA pruning to scan_stores_batched (stats parity)
-            keep = np.ones(nb, dtype=bool)
-            if predicate is not None:
-                for colname, (lo, hi) in predicate.bounds().items():
-                    if colname in c.smas:
-                        keep &= c.smas[colname].prune_blocks(lo, hi)
-            kept = np.flatnonzero(keep)
-            pruned += nb - kept.size
-            if kept.size == 0:
-                continue
-            stats.containers_scanned += 1
-            counts = c.smas[self.need[0]].counts
-            vblocks = cached_valid(cache, store, c, self.as_of, counts)
+        with obs.span("exec.cd_mask"):
+            for store, c in self.containers:
+                first = c.columns[self.need[0]]
+                nb, br = first.n_blocks, first.block_rows
+                total += nb
+                # identical SMA pruning to scan_stores_batched (stats parity)
+                keep = np.ones(nb, dtype=bool)
+                if predicate is not None:
+                    for colname, (lo, hi) in predicate.bounds().items():
+                        if colname in c.smas:
+                            keep &= c.smas[colname].prune_blocks(lo, hi)
+                kept = np.flatnonzero(keep)
+                pruned += nb - kept.size
+                if kept.size == 0:
+                    continue
+                stats.containers_scanned += 1
+                counts = c.smas[self.need[0]].counts
+                vblocks = cached_valid(cache, store, c, self.as_of, counts)
 
-            # ONE jitted mask program per container: validity slice plus
-            # every interval predicate (code range or decoded temporary)
-            encs = {name: enc_of(c, name)[1] for name in self.need}
-            meta = {name: c.columns[name] for name in self.need}
-            bounds: Dict[str, object] = {}
-            shape_key = []
-            for name, (lo, hi) in sorted(self.intervals.items()):
-                col = meta[name]
-                if col.encoding == Encoding.BLOCK_DICT \
-                        and "codes_packed" in col.arrays:
-                    clo, chi = _code_range(col, lo, hi)
-                    bounds[name] = jnp.asarray(
-                        np.stack([clo[kept], chi[kept]]))
-                    shape_key.append((name, "dict"))
-                else:
-                    # literals ride in as device scalars so a new literal
-                    # reuses the compiled program
-                    bounds[name] = tuple(
-                        None if b is None else jnp.asarray(b)
-                        for b in (lo, hi))
-                    shape_key.append((name, lo is None, hi is None))
-            fn = _jitted(("cmask", c.id, tuple(shape_key)),
-                         _make_mask_fn(meta, dict(self.intervals)))
-            mask, tmps = fn(vblocks, jnp.asarray(kept), encs, bounds)
-            segs.append((c, kept, mask, br, encs, tmps))
-        stats.blocks_pruned, stats.blocks_total = pruned, total
-        if not segs:
-            return None
+                # ONE jitted mask program per container: validity slice plus
+                # every interval predicate (code range or decoded temporary)
+                encs = {name: enc_of(c, name)[1] for name in self.need}
+                meta = {name: c.columns[name] for name in self.need}
+                bounds: Dict[str, object] = {}
+                shape_key = []
+                for name, (lo, hi) in sorted(self.intervals.items()):
+                    col = meta[name]
+                    if col.encoding == Encoding.BLOCK_DICT \
+                            and "codes_packed" in col.arrays:
+                        clo, chi = _code_range(col, lo, hi)
+                        bounds[name] = jnp.asarray(
+                            np.stack([clo[kept], chi[kept]]))
+                        shape_key.append((name, "dict"))
+                    else:
+                        # literals ride in as device scalars so a new literal
+                        # reuses the compiled program
+                        bounds[name] = tuple(
+                            None if b is None else jnp.asarray(b)
+                            for b in (lo, hi))
+                        shape_key.append((name, lo is None, hi is None))
+                fn = _jitted(("cmask", c.id, tuple(shape_key)),
+                             _make_mask_fn(meta, dict(self.intervals)))
+                mask, tmps = fn(vblocks, jnp.asarray(kept), encs, bounds)
+                segs.append((c, kept, mask, br, encs, tmps))
+            stats.blocks_pruned, stats.blocks_total = pruned, total
+            if not segs:
+                return None
 
-        flat = segs[0][2].reshape(-1) if len(segs) == 1 else \
-            jnp.concatenate([m.reshape(-1) for _, _, m, _, _, _ in segs])
+            flat = segs[0][2].reshape(-1) if len(segs) == 1 else \
+                jnp.concatenate([m.reshape(-1) for _, _, m, _, _, _ in segs])
         # the single host sync of the scan: survivor positions
-        surv = np.flatnonzero(np.asarray(flat))
+        with obs.span("exec.cd_sync"):
+            surv = np.flatnonzero(np.asarray(flat))
         n = int(surv.size)
         stats.rows_scanned = int(flat.shape[0])
-        stats.rows_materialized = n
-        # pad to the next pow2 so survivor-count jitter reuses programs
-        bucket = max(1, 1 << (n - 1).bit_length()) if n else 1
+        with obs.span("exec.cd_gather"):
+            # pad to the next pow2 so survivor-count jitter reuses programs
+            bucket = max(1, 1 << (n - 1).bit_length()) if n else 1
 
-        parts: Dict[str, List[jax.Array]] = {name: [] for name in self.need}
-        off = 0
-        for c, kept, mask, br, encs, tmps in segs:
-            lo_off, off = off, off + kept.size * br
-            s = surv[(surv >= lo_off) & (surv < off)] - lo_off
-            if s.size == 0:
-                continue
-            lb, r = np.divmod(s, br)             # local kept-block, row
-            # one upload: (global block, local kept-block, row) rows
-            idx = jnp.asarray(np.stack([kept[lb], lb, r]))
-            meta = {name: c.columns[name] for name in self.need}
-            umaps = {g: self.union_maps[(c.id, g)]
-                     for g in self.group_dicts if g in self.need}
-            # ONE jitted gather program per container: every need column
-            # (union codes / random-access gather / temp fancy-index)
-            fn = _jitted(("cgat", c.id, tuple(self.need),
-                          tuple(sorted(umaps)), tuple(sorted(tmps))),
-                         _make_gather_fn(meta, umaps, tuple(self.need)))
-            out = fn(encs, tmps, idx)
+            parts: Dict[str, List[jax.Array]] = {name: []
+                                                 for name in self.need}
+            off = 0
+            for c, kept, mask, br, encs, tmps in segs:
+                lo_off, off = off, off + kept.size * br
+                s = surv[(surv >= lo_off) & (surv < off)] - lo_off
+                if s.size == 0:
+                    continue
+                lb, r = np.divmod(s, br)             # local kept-block, row
+                # one upload: (global block, local kept-block, row) rows
+                idx = jnp.asarray(np.stack([kept[lb], lb, r]))
+                meta = {name: c.columns[name] for name in self.need}
+                umaps = {g: self.union_maps[(c.id, g)]
+                         for g in self.group_dicts if g in self.need}
+                # ONE jitted gather program per container: every need column
+                # (union codes / random-access gather / temp fancy-index)
+                fn = _jitted(("cgat", c.id, tuple(self.need),
+                              tuple(sorted(umaps)), tuple(sorted(tmps))),
+                             _make_gather_fn(meta, umaps, tuple(self.need)))
+                out = fn(encs, tmps, idx)
+                for name in self.need:
+                    parts[name].append(out[name])
+
+            cols: Dict[str, jax.Array] = {}
+            any_c = self.containers[0][1]
             for name in self.need:
-                parts[name].append(out[name])
-
-        cols: Dict[str, jax.Array] = {}
-        any_c = self.containers[0][1]
-        for name in self.need:
-            ps = parts[name]
-            if not ps:                           # zero survivors
-                dt = self._empty_dtype(name)
-                cols[name] = jnp.zeros(bucket, dt)
-            else:
-                # concat + dtype canonicalization (the exact dtypes the
-                # decoded scan would produce) + zero-pad to the bucket
-                fin = _jitted(("fin", len(ps), bucket),
-                              _make_finish_fn(bucket))
-                cols[name] = fin(tuple(ps))
-            col = any_c.columns[name]
-            if col.encoding == Encoding.FLOAT_SCALED \
-                    and name not in self.group_dicts:
-                # the gather program returned the INNER integer lanes;
-                # apply the scale division eagerly so its rounding is
-                # bit-identical to the eager decode_jnp path
-                cols[name] = cols[name].astype(jnp.float32) / col.scale
-        valid = jnp.arange(bucket) < n
-        if sip is not None:
-            valid = valid & sip(cols)
+                ps = parts[name]
+                if not ps:                           # zero survivors
+                    dt = self._empty_dtype(name)
+                    cols[name] = jnp.zeros(bucket, dt)
+                else:
+                    # concat + dtype canonicalization (the exact dtypes the
+                    # decoded scan would produce) + zero-pad to the bucket
+                    fin = _jitted(("fin", len(ps), bucket),
+                                  _make_finish_fn(bucket))
+                    cols[name] = fin(tuple(ps))
+                col = any_c.columns[name]
+                if col.encoding == Encoding.FLOAT_SCALED \
+                        and name not in self.group_dicts:
+                    # the gather program returned the INNER integer lanes;
+                    # apply the scale division eagerly so its rounding is
+                    # bit-identical to the eager decode_jnp path
+                    cols[name] = cols[name].astype(jnp.float32) / col.scale
+            valid = jnp.arange(bucket) < n
+            if sip is not None:
+                valid = valid & sip(cols)
         return ops.ScanResult(cols, valid, pruned, total)
 
     def _empty_dtype(self, name):
@@ -256,8 +260,11 @@ def _make_mask_fn(meta: Dict[str, EncodedColumn],
     """Build the per-container mask program (traced once per shape set):
     validity slice + every interval predicate.  Dict columns compare
     unpacked codes against per-block code ranges; other columns decode to
-    a temporary (returned for reuse by the gather program)."""
-    def fn(vblocks, kept, encs, bounds):
+    a temporary (returned for reuse by the gather program).  The inner
+    function's name names its program in a profile (``jit_cd_mask``), as
+    the gather's and the finish's do theirs."""
+    @jax.named_scope("cd_decode")
+    def cd_mask(vblocks, kept, encs, bounds):
         mask = vblocks[kept]
         tmps = {}
         for name in sorted(intervals):
@@ -280,7 +287,7 @@ def _make_mask_fn(meta: Dict[str, EncodedColumn],
                 if hi is not None:
                     mask = mask & (dec <= hi)
         return mask, tmps
-    return fn
+    return cd_mask
 
 
 def _make_gather_fn(meta: Dict[str, EncodedColumn],
@@ -290,7 +297,8 @@ def _make_gather_fn(meta: Dict[str, EncodedColumn],
     block, local kept-block, row-in-block)."""
     from ..kernels.bitunpack import gather_unpack
 
-    def fn(encs, tmps, idx):
+    @jax.named_scope("cd_gather")
+    def cd_gather(encs, tmps, idx):
         b, lb, r = idx[0], idx[1], idx[2]
         out = {}
         for name in need:
@@ -317,20 +325,20 @@ def _make_gather_fn(meta: Dict[str, EncodedColumn],
                 # sequential encoding: decode, then fancy-index survivors
                 out[name] = decode_jnp(col, encs[name])[b, r]
         return out
-    return fn
+    return cd_gather
 
 
 def _make_finish_fn(bucket: int):
     """Concat survivor parts, canonicalize dtype exactly like the decoded
     scan, zero-pad to the pow2 bucket."""
-    def fn(ps):
+    def cd_finish(ps):
         v = ps[0] if len(ps) == 1 else jnp.concatenate(ps)
         v = v.astype(jnp.float64 if v.dtype.kind == "f" else jnp.int64)
         if bucket > v.shape[0]:
             v = jnp.concatenate([v, jnp.zeros(bucket - v.shape[0],
                                               v.dtype)])
         return v
-    return fn
+    return cd_finish
 
 
 def _code_range(col: EncodedColumn, lo: Optional[int], hi: Optional[int]

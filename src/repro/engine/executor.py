@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.block_cache import BlockCache, KIND_DECODED, KIND_ENCODED
 from ..core.database import VerticaDB
 from ..core.encodings import decode_jnp, device_bytes, upload_jnp
@@ -178,6 +179,13 @@ def scan_stores_batched(db: VerticaDB, plan, need: Sequence[str],
     into one flat array per column.  Pruning decisions stay host-side
     (they read tiny SMA arrays); all row-level work happens in one device
     program downstream.  Returns None when everything was pruned."""
+    with obs.span("exec.scan"):
+        return _scan_stores(db, plan, need, predicate, sip, as_of, stats)
+
+
+def _scan_stores(db: VerticaDB, plan, need: Sequence[str],
+                 predicate: Optional[Expr], sip, as_of: int,
+                 stats) -> Optional[ops.ScanResult]:
     need = sorted(set(need) | (predicate.columns() if predicate else set()))
     cache = getattr(db, "block_cache", None)
     col_parts: Dict[str, List[jax.Array]] = {name: [] for name in need}
@@ -221,7 +229,8 @@ def scan_stores_batched(db: VerticaDB, plan, need: Sequence[str],
                 for n, p in col_parts.items()}
         valid = jnp.concatenate(valid_parts).reshape(-1)
     if predicate is not None:
-        valid = valid & jnp.asarray(predicate(cols), bool)
+        with jax.named_scope("predicate"):
+            valid = valid & jnp.asarray(predicate(cols), bool)
     if sip is not None:
         valid = valid & sip(cols)
     return ops.ScanResult({k: v for k, v in cols.items()}, valid,
@@ -416,7 +425,8 @@ def _build_fused(ir, predicate: Optional[Expr], algo: str,
         for name, e in ir.derived:
             cols[name] = e(cols)
         if predicate is not None:
-            valid = valid & jnp.asarray(predicate(cols), bool)
+            with jax.named_scope("predicate"):
+                valid = valid & jnp.asarray(predicate(cols), bool)
         values = {c: cols[c] for c in values_cols}
         if not group_by:
             keys = jnp.zeros(valid.shape[0], jnp.int32)
@@ -551,18 +561,18 @@ def execute_fused_deferred(db: VerticaDB, q, plan, as_of: int, stats
     # dict columns become code ranges, group keys stay codes, payloads
     # late-materialize for survivors only
     from .compressed import plan_compressed_scan
-    cplan = plan_compressed_scan(db, q, plan, need, scan_pred, as_of)
-    params = fused_plan_params(q, plan, stats,
-                               key_domains=cplan.key_domains(q, plan)
-                               if cplan is not None else None)
-    if params is None:
-        return None
-    algo, domain, domains = params
-
-    br = db.block_rows
-    sig = _plan_signature(db, q, plan, algo, domain, domains, br)
-    if cplan is not None:
-        sig = sig + cplan.sig_suffix
+    with obs.span("exec.dispatch"):
+        cplan = plan_compressed_scan(db, q, plan, need, scan_pred, as_of)
+        params = fused_plan_params(q, plan, stats,
+                                   key_domains=cplan.key_domains(q, plan)
+                                   if cplan is not None else None)
+        if params is None:
+            return None
+        algo, domain, domains = params
+        sig = _plan_signature(db, q, plan, algo, domain, domains,
+                              db.block_rows)
+        if cplan is not None:
+            sig = sig + cplan.sig_suffix
     if sig in _SORT_OVERFLOWED:
         return None   # known to exceed the sort cap: don't re-try
 
@@ -579,7 +589,8 @@ def execute_fused_deferred(db: VerticaDB, q, plan, as_of: int, stats
 
     # build sides host-side (small dims); the dim predicate filters here,
     # which is the SIP effect pushed all the way into the probe program
-    builds = build_join_sides(db, q, as_of)
+    with obs.span("exec.join_build"):
+        builds = build_join_sides(db, q, as_of)
     if q.joins:
         stats.sip_applied = stats.sip_applied or plan.use_sip
 
@@ -587,11 +598,12 @@ def execute_fused_deferred(db: VerticaDB, q, plan, as_of: int, stats
     # deferred one (join/derived columns) re-evaluates inside the program
     # (deterministic from pred+projection, both already in the signature)
     fused_pred = q.predicate if scan_pred is None else None
-    fused, hit = PLAN_CACHE.get_or_build(
-        sig, lambda: _build_fused(q, fused_pred, algo, domains, domain,
-                                  tuple(q.aggs)))
-    stats.plan_cache = "hit" if hit else "miss"
-    res = fused(scan.columns, scan.valid, tuple(builds))
+    with obs.span("exec.dispatch"):
+        fused, hit = PLAN_CACHE.get_or_build(
+            sig, lambda: _build_fused(q, fused_pred, algo, domains, domain,
+                                      tuple(q.aggs)))
+        stats.plan_cache = "hit" if hit else "miss"
+        res = fused(scan.columns, scan.valid, tuple(builds))
 
     def finish(host_res) -> Optional[Dict[str, np.ndarray]]:
         out = _shape_fused_result(q, host_res, algo, domain, domains,
@@ -613,7 +625,10 @@ def execute_fused(db: VerticaDB, q, plan, as_of: int,
     if d is None:
         return None
     res, finish = d
-    return finish(jax.device_get(res))
+    with obs.span("exec.result_sync"):
+        res = jax.device_get(res)
+    with obs.span("exec.finalize"):
+        return finish(res)
 
 
 def execute_shared_fused_deferred(db: VerticaDB, q, plan,

@@ -168,6 +168,7 @@ _COMBINE = {"sum": "add", "count": "add", "avg": "add",
 
 
 @partial(jax.jit, static_argnames=("domain", "aggs"))
+@jax.named_scope("groupby")
 def groupby_dense(keys: jax.Array, valid: jax.Array,
                   values: Dict[str, jax.Array],
                   domain: int, aggs: Tuple[Tuple[str, str, str], ...]):
@@ -199,6 +200,7 @@ def groupby_dense(keys: jax.Array, valid: jax.Array,
 
 
 @partial(jax.jit, static_argnames=("max_groups", "aggs"))
+@jax.named_scope("groupby")
 def groupby_sort(keys: jax.Array, valid: jax.Array,
                  values: Dict[str, jax.Array],
                  max_groups: int, aggs: Tuple[Tuple[str, str, str], ...]):
@@ -238,6 +240,7 @@ def groupby_sort(keys: jax.Array, valid: jax.Array,
     return out
 
 
+@jax.named_scope("groupby")
 def groupby_rle(key_col: EncodedColumn, valid_counts: np.ndarray,
                 domain: int) -> Dict[str, jax.Array]:
     """COUNT(*) GROUP BY key directly on RLE-encoded data: each run
@@ -318,6 +321,7 @@ def _agg_kind(name, aggs):
 # ---------------------------------------------------------------------------
 
 @jax.jit
+@jax.named_scope("join_probe")
 def join_lookup(build_keys: jax.Array, probe_keys: jax.Array):
     """Returns (idx, matched): for each probe key, the position of the
     matching build key (build keys unique, pre-sorted by caller)."""

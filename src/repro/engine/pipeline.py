@@ -26,12 +26,15 @@ thin constructors that lower via ``Query.to_ir()``; new code should use
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.database import VerticaDB
 from ..core.encodings import Encoding
 from .expr import Col, Expr
@@ -47,6 +50,9 @@ from .sip import sip_filter
 JoinSpec = LogicalJoin
 
 _PACK_LIMIT = 1 << 31   # packed keys live in device int32 by default
+
+# per-process query number: the ``qid`` of a query's spans
+_query_ids = itertools.count(1)
 
 _shim_warned = False
 
@@ -108,6 +114,7 @@ class ExecStats:
     sip_applied: bool = False
     wall_s: float = 0.0
     frontend_s: float = 0.0         # lowering + planning time
+    query_id: int = 0               # the ``qid`` of this query's spans
     # warm-path telemetry (engine/executor.py)
     fused: bool = False
     plan_cache: str = ""            # "hit" / "miss" / "" (not attempted)
@@ -115,10 +122,6 @@ class ExecStats:
     block_cache_misses: int = 0
     # compressed-domain execution telemetry (engine/compressed.py)
     compressed_scan: bool = False   # code-domain scan + late materialization
-    rows_materialized: int = 0      # survivor rows actually decoded
-    # per-stage wall times of the segmented path (engine/segmented.py):
-    # slab_build / exchange_join / preagg / final_merge, in milliseconds
-    stage_ms: Dict[str, float] = dataclasses.field(default_factory=dict)
     # segmented-execution telemetry (engine/segmented.py)
     segmented: bool = False
     n_shards: int = 0
@@ -148,23 +151,32 @@ def execute(db: VerticaDB, q, *, as_of: Optional[int] = None,
     (``db.attach_mesh()``) -- aggregate queries route through the
     segmented multi-device executor (engine/segmented.py) and fall back
     here for shapes outside its subset."""
+    qid = next(_query_ids)
+    with obs.span("exec.query", qid=qid):
+        return _execute(db, q, qid, as_of, plan, mesh, mesh_axis)
+
+
+def _execute(db: VerticaDB, q, qid: int, as_of: Optional[int], plan,
+             mesh, mesh_axis: str
+             ) -> Tuple[Dict[str, np.ndarray], ExecStats]:
     from ..planner.planner import plan_query
 
-    t0 = time.time()
-    q = as_ir(q)
-    if plan is None:
-        plan = plan_query(db, q)
-    if mesh is None:
-        mesh = getattr(db, "mesh", None)
-        mesh_axis = getattr(db, "mesh_axis", mesh_axis)
-    frontend_s = time.time() - t0
+    t0 = time.perf_counter()
+    with obs.span("exec.plan"):
+        q = as_ir(q)
+        if plan is None:
+            plan = plan_query(db, q)
+        if mesh is None:
+            mesh = getattr(db, "mesh", None)
+            mesh_axis = getattr(db, "mesh_axis", mesh_axis)
+    frontend_s = time.perf_counter() - t0
     from ..core.database import QueryRejectedError
     from ..core.faults import NodeCrashError, TransientFaultError
 
     stats = ExecStats(projection=plan.projection,
                       groupby_algorithm=plan.groupby_algorithm,
                       join_strategy=plan.join_strategy,
-                      frontend_s=frontend_s)
+                      frontend_s=frontend_s, query_id=qid)
     faults = getattr(db, "faults", None)
     f0 = faults.total_fired if faults is not None else 0
     # pin the cluster snapshot epoch for the query's lifetime (§5):
@@ -181,12 +193,13 @@ def execute(db: VerticaDB, q, *, as_of: Optional[int] = None,
 
         def _finish(out, *, final: bool = True):
             if final:
-                out = _finalize(q, out)
+                with obs.span("exec.finalize"):
+                    out = _finalize(q, out)
             stats.block_cache_hits = bc.hits - bc_h0
             stats.block_cache_misses = bc.misses - bc_m0
             if faults is not None:
                 stats.faults_injected = faults.total_fired - f0
-            stats.wall_s = time.time() - t0
+            stats.wall_s = time.perf_counter() - t0
             return out, stats
 
         retries_left = int(getattr(db, "max_failover_retries", 2))
@@ -236,14 +249,16 @@ def _execute_attempt(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
 
         # --- scalar COUNT directly on RLE runs (predicate on sort leader) ---
         if plan.scalar_rle:
-            res = _rle_scalar_count(db, q, plan, as_of)
+            with obs.span("exec.rle"):
+                res = _rle_scalar_count(db, q, plan, as_of)
             if res is not None:
                 stats.groupby_algorithm = "rle-scalar"
                 return _finish(res)
 
         # --- RLE-direct fast path: aggregate on encoded data, zero decode ---
         if rle_direct_eligible(q, plan):
-            res = _rle_groupby(db, q, plan, as_of)
+            with obs.span("exec.rle"):
+                res = _rle_groupby(db, q, plan, as_of)
             if res is not None:
                 return _finish(res)
             stats.groupby_algorithm = "sort (rle fallback)"
@@ -256,7 +271,8 @@ def _execute_attempt(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
             return _finish(res)
 
         # --- build sides + SIP (§6.1), one per join in plan order ---
-        builds = fused_exec.build_join_sides(db, q, as_of)
+        with obs.span("exec.join_build"):
+            builds = fused_exec.build_join_sides(db, q, as_of)
         sips: List[Callable] = []
         for ji, spec in enumerate(q.joins):
             if plan.sip_joins and plan.sip_joins[ji]:
@@ -277,8 +293,9 @@ def _execute_attempt(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
                                              scan_pred, sip, as_of, stats)
         if ros is not None:
             scans.append(ros)
-        scans.extend(wos_scan_results(db, plan, need, scan_pred, sip,
-                                      as_of))
+        with obs.span("exec.scan"):
+            scans.extend(wos_scan_results(db, plan, need, scan_pred, sip,
+                                          as_of))
         merged = ops.concat_scans(scans)
         if merged is None:
             return _finish(_empty_result(q))
@@ -302,10 +319,12 @@ def _execute_attempt(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
         if q.group_by or q.aggs:
             out = _run_groupby(q, plan, cols, valid, stats)
         else:
-            mask = np.asarray(valid)
             keep = set(q.columns) | {n for n, _ in q.derived}
-            out = {c: np.asarray(v)[mask] for c, v in cols.items()
-                   if (c in keep) or (not keep and c != "_matched")}
+            cols = {c: v for c, v in cols.items()
+                    if (c in keep) or (not keep and c != "_matched")}
+            with obs.span("exec.result_sync"):
+                mask, cols = jax.device_get((valid, cols))
+            out = {c: np.asarray(v)[mask] for c, v in cols.items()}
         return _finish(out)
     finally:
         # per-attempt bookkeeping only; the epoch pin is released by
@@ -469,7 +488,7 @@ def _rle_groupby(db: VerticaDB, q: LogicalQuery, plan, as_of: int
     dom = _domain_estimate(db, proj, group)
     if dom is None or dom > plan.dense_domain_limit:
         return None
-    total = np.zeros(dom, np.int64)
+    parts, pads = [], []
     for host, owner in plan.sources:
         store = db.nodes[host].stores[owner]
         if store.wos.n_rows:
@@ -479,15 +498,21 @@ def _rle_groupby(db: VerticaDB, q: LogicalQuery, plan, as_of: int
                 return None
             if c.columns[group].encoding != Encoding.RLE:
                 return None
-            counts = ops.groupby_rle(c.columns[group],
-                                     c.smas[group].counts, dom)
+            parts.append(ops.groupby_rle(c.columns[group],
+                                         c.smas[group].counts,
+                                         dom)["group_count"])
             # subtract tail-block padding (pad value = last value)
-            total += np.asarray(counts["group_count"])
             pad = c.columns[group].n_blocks * \
                 c.columns[group].block_rows - c.n_rows
             if pad and c.n_rows:
-                last = int(c.decode_column(group)[-1])
-                total[last] -= pad
+                pads.append((int(c.decode_column(group)[-1]), pad))
+    with obs.span("exec.result_sync"):
+        parts = jax.device_get(parts)
+    total = np.zeros(dom, np.int64)
+    for counts in parts:
+        total += np.asarray(counts)
+    for last, pad in pads:
+        total[last] -= pad
     sel = total > 0
     out = {group: np.flatnonzero(sel), "group_count": total[sel]}
     for name, _, kind in q.aggs:
@@ -509,6 +534,8 @@ def _run_groupby(q: LogicalQuery, plan, cols, valid, stats
         # scalar aggregate: single group
         keys = jnp.zeros(valid.shape[0], jnp.int32)
         res = ops.groupby_dense(keys, valid, values, 1, aggs)
+        with obs.span("exec.result_sync"):
+            res = jax.device_get(res)
         return {name: np.asarray(v)[:1] for name, v in res.items()}
 
     if not bool(valid.any()):
@@ -554,6 +581,8 @@ def _run_groupby(q: LogicalQuery, plan, cols, valid, stats
     if algo == "dense":
         res = ops.groupby_dense(packed.astype(jnp.int32), valid, values,
                                 total, aggs)
+        with obs.span("exec.result_sync"):
+            res = jax.device_get(res)
         counts = np.asarray(res["group_count"])
         sel = counts > 0
         gkeys = np.flatnonzero(sel)
@@ -562,6 +591,8 @@ def _run_groupby(q: LogicalQuery, plan, cols, valid, stats
             out[name] = np.asarray(res[name])[sel]
     else:
         res = ops.groupby_sort(packed, valid, values, plan.max_groups, aggs)
+        with obs.span("exec.result_sync"):
+            res = jax.device_get(res)
         n = int(res["n_groups"])
         if n > plan.max_groups:
             # more distinct groups than the sort cap: groupby_sort would

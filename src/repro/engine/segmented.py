@@ -56,7 +56,6 @@ group domains past the device integer width, or an empty snapshot.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -1092,26 +1091,10 @@ def execute_segmented(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
     need |= set(reseg_keys)
     need = sorted(need & set(proj.columns))
 
-    # per-stage wall clocks (ExecStats.stage_ms): opt-in because honest
-    # stage boundaries need a device sync, which the pipelined normal
-    # path must not pay.  The cstore bench's segmented tier flips this on.
-    timing = bool(getattr(db, "collect_stage_timing", False))
-
-    def _tick(label: str, t0: float, out) -> float:
-        if timing:
-            jax.block_until_ready(jax.tree.leaves(out))
-            t1 = time.perf_counter()
-            stats.stage_ms[label] = stats.stage_ms.get(label, 0.0) \
-                + (t1 - t0) * 1e3
-            return t1
-        return t0
-
-    t0 = time.perf_counter() if timing else 0.0
     slab = _sharded_scan(db, proj, plan, q, need, reseg_keys, as_of, mesh,
                          axis, n_shards, stats)
     if slab is None:
         return None               # empty snapshot: pipeline shapes it
-    _tick("slab_build", t0, (slab["cols"], slab["valid"]))
 
     builds, build_specs, build_bounds = _place_builds(
         db, q, plan, as_of, mesh, axis, n_shards, stats)
@@ -1173,7 +1156,6 @@ def execute_segmented(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
         per_prev, real_prev = slab["per"], slab["r0"]
         overflows = []
         res = None
-        ts = time.perf_counter() if timing else 0.0
         for si, stage in enumerate(stage_joins):
             final = si == len(stage_joins) - 1
             reseg_key = None
@@ -1226,10 +1208,8 @@ def execute_segmented(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
             if final:
                 # the final fused stage ends in the shard-local scatter
                 # pre-aggregation (kernels/seg_preagg)
-                ts = _tick("preagg", ts, out)
                 res = out
             else:
-                ts = _tick("exchange_join", ts, out)
                 valid = out.pop("__valid")
                 dest_cols = {k[4:]: v for k, v in out.items()
                              if k.startswith("__d:")}
@@ -1256,7 +1236,6 @@ def execute_segmented(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
     stats.plan_cache = "hit" if hit_all else "miss"
 
     # ---- final merge ----
-    t0 = time.perf_counter() if timing else 0.0
     if not q.group_by:
         out = _merge_scalar(aggs, res, n_shards)
     else:
@@ -1270,7 +1249,6 @@ def execute_segmented(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
             else [np.asarray(gkeys).astype(np.int64)]
         for g, kv in zip(q.group_by, key_cols):
             out[g] = kv
-    _tick("final_merge", t0, ())
     stats.segmented = True
     stats.n_shards = n_shards
     stats.exchange = ";".join(plan.join_exchanges)
