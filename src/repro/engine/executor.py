@@ -370,36 +370,51 @@ def _plan_signature(db: VerticaDB, q, plan, algo: str, domain: int,
             int(domain), tuple(domains), br)
 
 
-def build_join_sides(db: VerticaDB, q, as_of: int
-                     ) -> List[Dict[str, jax.Array]]:
+def _tree_bytes(tree) -> int:
+    return sum(map(device_bytes, jax.tree_util.tree_leaves(tree)))
+
+
+def build_join_sides(db: VerticaDB, q, as_of: int, stats=None
+                     ) -> Tuple[List[Dict[str, jax.Array]],
+                                Tuple[Optional[ops.DenseIndex], ...]]:
     """Build sides for the IR's join list: snapshot-read each dimension,
     apply its dim predicate, upload key + carried columns.  Shared by the
     fused and general pipelines, and kept device-resident in the block
     cache keyed by (dim table, join signature, snapshot epoch) -- MVCC
     makes a fixed-epoch read immutable, so a repeat join query skips the
     host decode + upload entirely (drop_partition, the one non-MVCC
-    mutation, invalidates the table's entries)."""
+    mutation, invalidates the table's entries).
+
+    Returns the build dicts and, beside them, each side's dense position
+    index (``operators.dense_index``; None where its keys admit none),
+    built once with the side and cached in the same entry.  The index
+    stays out of the build dict, whose every non-key column a join
+    carries.  ``stats.join_probe`` records the probe each join takes."""
     cache = getattr(db, "block_cache", None)
-    builds = []
+    sides = []
     for spec in q.joins:
         def make(spec=spec):
             dim_rows = db.read_table(spec.dim_table, as_of=as_of)
             if spec.dim_predicate is not None:
                 m = np.asarray(spec.dim_predicate(dim_rows), bool)
                 dim_rows = {c: v[m] for c, v in dim_rows.items()}
-            return {c: jnp.asarray(dim_rows[c])
-                    for c in (spec.dim_key,) + tuple(spec.dim_columns)}
+            build = {c: jnp.asarray(dim_rows[c])
+                     for c in (spec.dim_key,) + tuple(spec.dim_columns)}
+            return build, ops.dense_index(np.asarray(dim_rows[spec.dim_key]))
         if cache is None:
-            builds.append(make())
+            sides.append(make())
         else:
             # effective-epoch key: as-of clamped to the dim table's epoch
             # ceiling, so trickle loads into OTHER tables advance the
             # cluster epoch without evicting this build side
             eff = min(as_of, db.table_epoch_ceiling(spec.dim_table))
-            builds.append(cache.get_or_put(
+            sides.append(cache.get_or_put(
                 f"dim:{spec.dim_table}", f"{spec.signature()}@{eff}",
-                KIND_BUILD, make, device_bytes))
-    return builds
+                KIND_BUILD, make, _tree_bytes))
+    if stats is not None:
+        stats.join_probe = ";".join("search" if d is None else "dense"
+                                    for _, d in sides)
+    return [b for b, _ in sides], tuple(d for _, d in sides)
 
 
 def _build_fused(ir, predicate: Optional[Expr], algo: str,
@@ -417,11 +432,13 @@ def _build_fused(ir, predicate: Optional[Expr], algo: str,
 
     @jax.jit
     def fused(cols: Dict[str, jax.Array], valid: jax.Array,
-              builds: Tuple[Dict[str, jax.Array], ...]):
+              builds: Tuple[Dict[str, jax.Array], ...],
+              dense: Tuple[Optional[ops.DenseIndex], ...]):
         cols = dict(cols)
-        for spec, build in zip(ir.joins, builds):
+        for spec, build, idx in zip(ir.joins, builds, dense):
             cols, valid = ops.hash_join(build, spec.dim_key, cols,
-                                        spec.fact_key, valid, how=spec.how)
+                                        spec.fact_key, valid, how=spec.how,
+                                        dense=idx)
         for name, e in ir.derived:
             cols[name] = e(cols)
         if predicate is not None:
@@ -590,7 +607,7 @@ def execute_fused_deferred(db: VerticaDB, q, plan, as_of: int, stats
     # build sides host-side (small dims); the dim predicate filters here,
     # which is the SIP effect pushed all the way into the probe program
     with obs.span("exec.join_build"):
-        builds = build_join_sides(db, q, as_of)
+        builds, dense = build_join_sides(db, q, as_of, stats)
     if q.joins:
         stats.sip_applied = stats.sip_applied or plan.use_sip
 
@@ -603,7 +620,7 @@ def execute_fused_deferred(db: VerticaDB, q, plan, as_of: int, stats
             sig, lambda: _build_fused(q, fused_pred, algo, domains, domain,
                                       tuple(q.aggs)))
         stats.plan_cache = "hit" if hit else "miss"
-        res = fused(scan.columns, scan.valid, tuple(builds))
+        res = fused(scan.columns, scan.valid, tuple(builds), dense)
 
     def finish(host_res) -> Optional[Dict[str, np.ndarray]]:
         out = _shape_fused_result(q, host_res, algo, domain, domains,
@@ -666,7 +683,7 @@ def execute_shared_fused_deferred(db: VerticaDB, q, plan,
         sig, lambda: _build_fused(q, q.predicate, algo, domains, domain,
                                   tuple(q.aggs)))
     stats.plan_cache = "hit" if hit else "miss"
-    res = fused(cols, valid, ())
+    res = fused(cols, valid, (), ())
 
     def finish(host_res) -> Optional[Dict[str, np.ndarray]]:
         # overflow poisons BOTH signatures: the dedicated path would

@@ -16,7 +16,8 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -320,6 +321,35 @@ def _agg_kind(name, aggs):
 # Join (N:1 lookup = hash join; same primitive is a merge join on sorted)
 # ---------------------------------------------------------------------------
 
+class DenseIndex(NamedTuple):
+    """Position table of an N:1 build side over a narrow integer key
+    range: ``pos[k - lo]`` is the build row holding key ``k``, or -1."""
+    pos: jax.Array      # (R,) int32
+    lo: jax.Array       # () in the build key's device dtype
+
+
+def dense_index(keys: np.ndarray) -> Optional[DenseIndex]:
+    """The dense position index of a build side's host key column, or
+    None when the keys do not admit one: not integers, empty, duplicated,
+    outside the device key dtype, or spread over more than
+    ``max(4 n, 2**20)`` values (at most 16 bytes a build row, or a 4 MiB
+    table for small builds)."""
+    n = keys.shape[0]
+    if n == 0 or keys.dtype.kind not in "iu":
+        return None
+    lo, hi = int(keys.min()), int(keys.max())
+    r = hi - lo + 1
+    dt = jax.dtypes.canonicalize_dtype(keys.dtype)
+    info = np.iinfo(dt)
+    if r > max(4 * n, 1 << 20) or lo < info.min or hi > info.max:
+        return None
+    pos = np.full(r, -1, np.int32)
+    pos[(keys - lo).astype(np.int64)] = np.arange(n, dtype=np.int32)
+    if np.count_nonzero(pos >= 0) != n:
+        return None   # duplicate keys: not an N:1 build side
+    return DenseIndex(jnp.asarray(pos), jnp.asarray(lo, dt))
+
+
 @jax.jit
 @jax.named_scope("join_probe")
 def join_lookup(build_keys: jax.Array, probe_keys: jax.Array):
@@ -331,13 +361,31 @@ def join_lookup(build_keys: jax.Array, probe_keys: jax.Array):
     return idx, matched
 
 
+@jax.jit
+@jax.named_scope("join_probe")
+def dense_lookup(pos: jax.Array, lo: jax.Array, probe_keys: jax.Array):
+    """:func:`join_lookup` through a :class:`DenseIndex`: one gather per
+    probe key.  ``idx`` is a build row in the build side's own order."""
+    dt = jnp.result_type(probe_keys, lo)
+    k, lo = probe_keys.astype(dt), lo.astype(dt)
+    r = pos.shape[0]
+    # compare against both ends before subtracting: ``k - lo`` may wrap
+    # for keys far outside the range
+    inr = (k >= lo) & (k <= lo + (r - 1))
+    p = pos[jnp.clip(k - lo, 0, r - 1)]
+    matched = inr & (p >= 0)
+    return jnp.maximum(p, 0), matched
+
+
 def hash_join(build: Dict[str, jax.Array], build_key: str,
               probe: Dict[str, jax.Array], probe_key: str,
               probe_valid: jax.Array,
-              how: str = "inner") -> Tuple[Dict[str, jax.Array], jax.Array]:
+              how: str = "inner", dense: Optional[DenseIndex] = None
+              ) -> Tuple[Dict[str, jax.Array], jax.Array]:
     """N:1 join: probe each fact row against the (small) build side.
-    Build side is sorted once ('building the hash table'); the probe is one
-    vectorized lookup. Returns (joined columns, valid mask)."""
+    With a ``dense`` index of the build keys the probe is one gather;
+    without one the build side is sorted ('building the hash table') and
+    probed by binary search.  Returns (joined columns, valid mask)."""
     if build[build_key].shape[0] == 0:
         # empty build side (dim predicate filtered everything, or the
         # dimension was truncated): no probe row can match
@@ -353,14 +401,17 @@ def hash_join(build: Dict[str, jax.Array], build_key: str,
             out["_matched"] = matched
             return out, probe_valid
         raise ValueError(how)
-    order = jnp.argsort(build[build_key])
-    bk = build[build_key][order]
-    idx, matched = join_lookup(bk, probe[probe_key])
+    if dense is not None and jnp.issubdtype(probe[probe_key].dtype,
+                                            jnp.integer):
+        idx, matched = dense_lookup(dense.pos, dense.lo, probe[probe_key])
+        rows = {c: v for c, v in build.items() if c != build_key}
+    else:
+        order = jnp.argsort(build[build_key])
+        idx, matched = join_lookup(build[build_key][order], probe[probe_key])
+        rows = {c: v[order] for c, v in build.items() if c != build_key}
     out = dict(probe)
-    for c, v in build.items():
-        if c == build_key:
-            continue
-        joined = v[order][idx]
+    for c, v in rows.items():
+        joined = v[idx]
         if how == "left":
             # unmatched rows carry the NULL sentinel (-1), the engine's
             # NULL analog, instead of an arbitrary clipped build row

@@ -126,6 +126,8 @@ class ExecStats:
     segmented: bool = False
     n_shards: int = 0
     exchange: str = ""              # ";"-joined per-join exchange ops
+    join_probe: str = ""            # ";"-joined per-join probe: "dense"
+    #                                 (position index) or "search"
     reseg_overflow: int = 0         # tuples that hit a full exchange slot
     seg_slab: str = ""              # ROS slab "hit"/"miss", "+wos" when a
     #                                 trickle-load delta slab was appended
@@ -272,7 +274,8 @@ def _execute_attempt(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
 
         # --- build sides + SIP (§6.1), one per join in plan order ---
         with obs.span("exec.join_build"):
-            builds = fused_exec.build_join_sides(db, q, as_of)
+            builds, dense = fused_exec.build_join_sides(db, q, as_of,
+                                                         stats)
         sips: List[Callable] = []
         for ji, spec in enumerate(q.joins):
             if plan.sip_joins and plan.sip_joins[ji]:
@@ -305,9 +308,10 @@ def _execute_attempt(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
         stats.rows_scanned = int(cols[next(iter(cols))].shape[0])
 
         # --- joins (in plan order; later probes may use earlier outputs) ---
-        for spec, build in zip(q.joins, builds):
+        for spec, build, idx in zip(q.joins, builds, dense):
             cols, valid = ops.hash_join(build, spec.dim_key, cols,
-                                        spec.fact_key, valid, how=spec.how)
+                                        spec.fact_key, valid, how=spec.how,
+                                        dense=idx)
 
         # --- derived projections, then any deferred predicate ---
         for name, e in q.derived:

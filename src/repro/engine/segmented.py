@@ -818,7 +818,7 @@ def _place_builds(db: VerticaDB, q: LogicalQuery, plan, as_of: int, mesh,
     epoch) -- MVCC makes the fixed-epoch read immutable, so a warm
     repeat skips the host round-trip, re-partition AND (for broadcast
     joins) the all_gather; drop_partition invalidates the dim's entries."""
-    builds_dev = fused_exec.build_join_sides(db, q, as_of)
+    builds_dev, _ = fused_exec.build_join_sides(db, q, as_of)
     cache = getattr(db, "block_cache", None)
     mh = hash(_mesh_sig(mesh, axis)) & 0xFFFFFFFFFFFFFFFF
     placed, specs, bounds = [], [], []
@@ -1252,5 +1252,7 @@ def execute_segmented(db: VerticaDB, q: LogicalQuery, plan, as_of: int,
     stats.segmented = True
     stats.n_shards = n_shards
     stats.exchange = ";".join(plan.join_exchanges)
+    # placed builds are hash-partitioned or gathered: binary search
+    stats.join_probe = ";".join("search" for _ in q.joins)
     stats.groupby_algorithm = f"{algo} (segmented)"
     return out
