@@ -152,8 +152,9 @@ def _window(queries: dict, order, seconds: float, span) -> tuple:
 
 
 def _check(answers: dict, specs: dict, lits: dict, tables: dict,
-           limits: dict) -> tuple:
-    """Every answer against the reference: (correct, checks)."""
+           limits: dict, off_mesh: Optional[int]) -> tuple:
+    """Every answer against the reference, and on a mesh the queries
+    that left it: (correct, checks)."""
     t_ref = time.perf_counter()
     parts = []
     for (t, k), outs in answers.items():
@@ -161,7 +162,30 @@ def _check(answers: dict, specs: dict, lits: dict, tables: dict,
         parts.extend(compare.readings(specs[t], o, want) for o in outs)
     log(f"reference: {len(answers)} distinct queries in "
         f"{time.perf_counter() - t_ref:.3f} s")
-    return compare.verdict(compare.combine(parts), limits)
+    values = compare.combine(parts)
+    if off_mesh is not None:
+        values["off_mesh"] = off_mesh
+    return compare.verdict(values, limits)
+
+
+def _check_mesh(config: dict, cell: dict, devices: list) -> Optional[int]:
+    """The chips a deployment puts its nodes on (None: it states none),
+    refused unless the cell asks for as many, the process sees them and
+    the deployment gives ``off_mesh`` a limit."""
+    chips = config["cluster"].get("chips")
+    if chips is None:
+        return None
+    chips = int(chips)
+    if chips != int(cell["chips"]):
+        raise BenchError(f"the configuration puts its nodes on {chips} "
+                         f"chips, the cell asks for {cell['chips']}")
+    if chips > len(devices):
+        raise BenchError(f"the configuration puts its nodes on {chips} "
+                         f"chips, the process sees {len(devices)}")
+    if "off_mesh" not in config["limits"]:
+        raise BenchError("a configuration with cluster.chips needs "
+                         "limits.off_mesh")
+    return chips
 
 
 def run_cell(root: pathlib.Path, workload_name: str, seed: int,
@@ -173,6 +197,7 @@ def run_cell(root: pathlib.Path, workload_name: str, seed: int,
     r = resolve(root, load_bench(root), workload_name)
     config, traffic = r["config"], r["traffic"]
     specs = traffic["templates"]
+    chips = _check_mesh(config, r["cell"], devices)
     counter = CompileCounter()
 
     t0 = time.perf_counter()
@@ -184,7 +209,8 @@ def run_cell(root: pathlib.Path, workload_name: str, seed: int,
                      for t, c in tables.items())
     log(f"data: {rows}; generated in {t_gen - t0:.3f} s, loaded in "
         f"{t_load - t_gen:.3f} s; block-cache budget "
-        f"{db.block_cache.budget_bytes} B")
+        f"{db.block_cache.budget_bytes} B; "
+        f"{'no mesh' if chips is None else f'mesh of {chips} chips'}")
     lits = workload.literal_sets(traffic)
     queries = {(t, k): program.build_query(db, specs[t], lits[t][k])
                for t, k in workload.all_pairs(traffic)}
@@ -208,11 +234,18 @@ def run_cell(root: pathlib.Path, workload_name: str, seed: int,
     log(f"window: {len(records)} queries in {window_s:.3f} s; "
         f"{counter.count} programs compiled or loaded inside it")
     for t in specs:
-        lat = sorted(q["latency_s"] * 1e3 for q in records
-                     if q["template"] == t)
+        mine = [q for q in records if q["template"] == t]
+        lat = sorted(q["latency_s"] * 1e3 for q in mine)
         if lat:
             log(f"  {t}: {len(lat)} queries, median "
                 f"{lat[len(lat) // 2]:.3f} ms, max {lat[-1]:.3f} ms")
+        if lat and chips is not None:
+            st = [q["stats"] or {} for q in mine]
+            log(f"  {t} on the mesh: {compare.off_mesh(mine)} off it; "
+                f"shards {sorted({s.get('n_shards') for s in st})}, "
+                f"exchange {sorted({s.get('exchange') for s in st})}, "
+                f"slab {sorted({s.get('seg_slab') for s in st})}, "
+                f"overflow {sum(s.get('reseg_overflow', 0) for s in st)}")
 
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in devices)
@@ -227,7 +260,9 @@ def run_cell(root: pathlib.Path, workload_name: str, seed: int,
         log(f"trace read in {time.perf_counter() - t_tr:.3f} s: busy "
             f"{reduced.busy_s:.6f} s of {reduced.window_s:.6f} s")
 
-    correct, checks = _check(answers, specs, lits, tables, config["limits"])
+    correct, checks = _check(
+        answers, specs, lits, tables, config["limits"],
+        None if chips is None else compare.off_mesh(records))
 
     ctx = Context(queries=records, window_s=window_s, setup_s=setup_s,
                   trace=reduced)

@@ -25,7 +25,10 @@ def _schema(name: str, spec: dict):
 
 
 def load(config: dict, tables: Dict[str, Dict[str, np.ndarray]]):
-    """A ``VerticaDB`` for the deployment, holding ``tables``."""
+    """A ``VerticaDB`` for the deployment, holding ``tables``.  A
+    deployment that states ``cluster.chips`` gets a query mesh of that
+    many devices, one segmentation shard each, attached before any query
+    is built: its queries then run through the segmented executor."""
     from repro.core import Encoding, VerticaDB
     from repro.core.projection import super_projection
 
@@ -47,6 +50,9 @@ def load(config: dict, tables: Dict[str, Dict[str, np.ndarray]]):
     for name, data in tables.items():
         db.insert(t, name, data)
     db.commit(t)
+    if "chips" in cl:
+        from repro.distributed.mesh import make_query_mesh
+        db.attach_mesh(make_query_mesh(int(cl["chips"])))
     if "packed_fit" in cache:
         db.block_cache.budget_bytes = packed_fit_budget(
             db, cache["packed_fit"]["table"], cache["packed_fit"]["columns"])
@@ -99,10 +105,14 @@ def build_query(db, spec: dict, lits: Dict[str, int]):
 
 
 def stats_of(builder) -> Optional[dict]:
-    """The ``ExecStats`` counters the per-layer metrics read."""
+    """The ``ExecStats`` counters the per-layer metrics and the check
+    read: the scan's, and the segmented executor's (``segmented`` is
+    true only where it answered; ``exchange``, ``join_probe`` one entry
+    per join)."""
     st = builder.stats
     if st is None:
         return None
     return {k: getattr(st, k) for k in (
         "frontend_s", "blocks_pruned", "blocks_total", "block_cache_hits",
-        "block_cache_misses")}
+        "block_cache_misses", "segmented", "n_shards", "exchange",
+        "join_probe", "reseg_overflow", "seg_slab")}

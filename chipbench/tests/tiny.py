@@ -44,6 +44,37 @@ def make_root(tmp: pathlib.Path, *, rows: int = ROWS,
     return root
 
 
+def add_mesh_cell(root: pathlib.Path, chips: int, *,
+                  literal_sets: int = 2) -> str:
+    """A deployment that puts ``cstore7_sf2``'s nodes on ``chips`` chips
+    (``limits.off_mesh`` 0), under the planned segmented mix (Q2, Q3, Q4,
+    Q6 of ``scan_agg`` and Q5 of ``join``), added as new files plus
+    BENCHMARK.json entries; returns the cell's name."""
+    cb = root / "chipbench"
+    cfg = json.loads((cb / "configs" / "cstore7_sf2.json").read_text())
+    cfg["name"] = "test_only_mesh"
+    cfg["cluster"]["chips"] = chips
+    cfg["limits"]["off_mesh"] = 0
+    (cb / "configs" / "test_only_mesh.json").write_text(json.dumps(cfg))
+    scan = json.loads((CHIPBENCH / "traffic" / "scan_agg.json").read_text())
+    join = json.loads((CHIPBENCH / "traffic" / "join.json").read_text())
+    templates = {t: scan["templates"][t] for t in ("Q2", "Q3", "Q4", "Q6")}
+    templates["Q5"] = join["templates"]["Q5"]
+    (cb / "traffic" / "test_only_seg.json").write_text(json.dumps({
+        "client": {"kind": "closed", "clients": 1},
+        "literal_sets": literal_sets, "templates": templates}))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "test_only_mesh", "source": "test",
+                             "file": "chipbench/configs/test_only_mesh.json",
+                             "reduced": [], "why": "test"})
+    name = "test_only_mesh.seg"
+    bench["workloads"].append({
+        "name": name, "config": "test_only_mesh", "traffic": "test_only_seg",
+        "chips": chips, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return name
+
+
 def run(root: pathlib.Path, cell: str, *, seed: int = 2**31 + 7,
         seconds: float = 0.5) -> dict:
     """``run_cell`` on the CPU, past the harness's look for a TPU."""
